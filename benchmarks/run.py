@@ -165,4 +165,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -11,6 +11,9 @@ registry.
 from repro.configs import get_logreg_config
 from repro.core import build_problem, build_test_problem, make_solver
 from repro.data.synthetic import generate
+from repro.utils.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # 1. a federated dataset: K clients, power-law sizes, per-client skew
 cfg = get_logreg_config().scaled(0.005)

@@ -56,4 +56,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import EngineConfig, RoundEngine
 from repro.core.fedavg import FedAvg, FedAvgConfig
-from repro.core.problem import FederatedLogReg
+from repro.core.problem import FederatedLogReg, map_clients
 from repro.core.registry import register
 from repro.core.solver import FederatedSolver, SolverState
 
@@ -45,7 +45,9 @@ def _gd_client_pass(w, bucket, lam, stepsize):
         g = jnp.zeros_like(w).at[idx].add(g_sc[:, None] * val)
         return -stepsize * (g + lam * w)
 
-    return jax.vmap(one_client)(bucket.idx, bucket.val, bucket.y, bucket.n_k)
+    return map_clients(jax.vmap(one_client),
+                       (bucket.idx, bucket.val, bucket.y, bucket.n_k),
+                       w.shape[0])
 
 
 class DistributedGD(FederatedSolver):
@@ -79,12 +81,9 @@ class DistributedGD(FederatedSolver):
                                                guard_trim=guard_trim),
                                   participation_model=participation_model,
                                   fault_model=fault_model)
-        self._passes = [] if virtual else [
-            jax.jit(functools.partial(_gd_client_pass, bucket=b,
-                                      lam=problem.flat.lam, stepsize=stepsize))
-            for b in problem.buckets
-        ]
-        gd_pass = lambda w, bi, b, kb: self._passes[bi](w)
+        self._pass = jax.jit(functools.partial(
+            _gd_client_pass, lam=problem.flat.lam, stepsize=stepsize))
+        gd_pass = lambda w, bi, b, kb: self._pass(w, b)
         # deterministic pass: the per-client keys of the streamed contract
         # are simply unused
         gd_chunk_pass = lambda w, bi, cb, keys: _gd_client_pass(

@@ -36,9 +36,10 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.scipy.special import xlogy
 
 from repro.core.engine import EngineConfig, RoundEngine
-from repro.core.problem import ClientBucket, FederatedLogReg
+from repro.core.problem import ClientBucket, FederatedLogReg, map_clients
 from repro.core.registry import register
 from repro.core.solver import FederatedSolver, SolverState
 
@@ -70,8 +71,8 @@ def _sdca_local_pass(w, alpha_b, bucket: ClientBucket, lam, n, sigma,
     t every client processes the t-th coordinate of its own permutation
     (clients are independent, so lockstep order is exactly the per-client
     sequential order), which turns the clipped-Newton β-solve into ONE
-    (Kb,)-vector call per step — the fused Pallas kernel when
-    ``use_kernel``, the identical jnp recursion elsewhere.
+    vector call per step over a batch of clients — the fused Pallas kernel
+    when ``use_kernel``, the identical jnp recursion elsewhere.
     """
     keys = jax.random.split(key, bucket.num_clients)
     return _sdca_local_pass_keyed(w, alpha_b, bucket, lam, n, sigma,
@@ -82,7 +83,22 @@ def _sdca_local_pass_keyed(w, alpha_b, bucket: ClientBucket, lam, n, sigma,
                            use_kernel, keys):
     """:func:`_sdca_local_pass` over explicit per-client keys — the engine's
     streamed (``client_chunk``) path hands in chunk-sized bucket/state
-    slices with the matching slice of the bucket's key split."""
+    slices with the matching slice of the bucket's key split.  Clients are
+    independent, so the lockstep pass runs batch by batch
+    (:func:`~repro.core.problem.map_clients`)."""
+    return map_clients(
+        lambda a, idx, val, y, n_k, ks: _sdca_lockstep(
+            w, a, ClientBucket(idx, val, y, n_k), lam, n, sigma, use_kernel,
+            ks),
+        (alpha_b, bucket.idx, bucket.val, bucket.y, bucket.n_k, keys),
+        w.shape[0])
+
+
+def _sdca_lockstep(w, alpha_b, bucket: ClientBucket, lam, n, sigma,
+                   use_kernel, keys):
+    """The SDCA pass of a batch of clients in lockstep (see
+    :func:`_sdca_local_pass`); returns the dual steps u (Kb, m_pad) and the
+    primal sums r = X_k u (Kb, d)."""
     Kb = bucket.num_clients
     m_pad = bucket.m_pad
     d = w.shape[0]
@@ -192,11 +208,8 @@ class CoCoAPlus(FederatedSolver):
         lam = problem.flat.lam
         self._scale = 1.0 / (lam * n)
         virtual = cfg.virtual_data or problem.virtual is not None
-        self._pass = [] if virtual else [
-            jax.jit(lambda w, a, key, b=b: _sdca_local_pass(
-                w, a, b, lam, n, self.sigma, use_kernel, key))
-            for b in problem.buckets
-        ]
+        self._pass = jax.jit(lambda w, a, b, key: _sdca_local_pass(
+            w, a, b, lam, n, self.sigma, use_kernel, key))
         self.engine = RoundEngine(
             problem,
             EngineConfig(weighting="sum", participation=cfg.participation,
@@ -212,7 +225,7 @@ class CoCoAPlus(FederatedSolver):
         )
 
         def cocoa_pass(w, bi, bucket, alpha_b, kb):
-            u, r = self._pass[bi](w, alpha_b, kb)
+            u, r = self._pass(w, alpha_b, bucket, kb)
             return r * self._scale, alpha_b + u
 
         def cocoa_chunk_pass(w, bi, chunk_bucket, alpha_c, keys):
@@ -239,6 +252,25 @@ class CoCoAPlus(FederatedSolver):
         w, alphas = self._round_fast(state.w, state.aux, key,
                                      round_index=state.round)
         return SolverState(w=w, aux=alphas, round=state.round + 1)
+
+    def dual_objective(self, state: SolverState) -> jax.Array:
+        """D(α) = (1/n) Σ_i H(β_i) − (λ/2)||w||², with β_i = y_i α_i and H
+        the binary entropy (−ℓ*(−α_i) for the logistic loss ℓ).
+
+        This is what CoCoA+'s rounds ascend — monotonically for the safe
+        σ′ = γK — and D(α) ≤ f(w*) ≤ f(w(α)).  The primal f(w(α)) carries
+        no such guarantee: from α = 0 it can rise above f(0) = log 2 for
+        rounds before it falls."""
+        flat = self.problem.flat
+        ent = jnp.zeros(())
+        for b, a in zip(self.problem.buckets, state.aux):
+            if self.problem.virtual is not None:
+                b = self.problem.virtual.realize(b)
+            beta = jnp.clip(b.y * a, 0.0, 1.0)
+            h = -(xlogy(beta, beta) + xlogy(1.0 - beta, 1.0 - beta))
+            valid = jnp.arange(b.m_pad)[None, :] < b.n_k[:, None]
+            ent = ent + jnp.where(valid, h, 0.0).sum()
+        return ent / flat.n - 0.5 * flat.lam * jnp.dot(state.w, state.w)
 
     @property
     def hyperparams(self):

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import EngineConfig, RoundEngine
-from repro.core.problem import ClientBucket, FederatedLogReg
+from repro.core.problem import ClientBucket, FederatedLogReg, map_clients
 from repro.core.registry import register
 from repro.core.solver import FederatedSolver, SolverState
 
@@ -128,7 +128,9 @@ def _dane_gd_pass(w0, full_grad, bucket: ClientBucket, lam, cfg: DANEConfig,
         wk, _ = jax.lax.scan(gd_step, w0, None, length=cfg.local_steps)
         return wk - w0
 
-    return jax.vmap(one_client)(bucket.idx, bucket.val, bucket.y, bucket.n_k)
+    return map_clients(jax.vmap(one_client),
+                       (bucket.idx, bucket.val, bucket.y, bucket.n_k),
+                       w0.shape[0])
 
 
 def _dane_svrg_pass(w0, full_grad, bucket: ClientBucket, lam, cfg: DANEConfig,
@@ -185,8 +187,9 @@ def _dane_svrg_pass_keyed(w0, full_grad, bucket: ClientBucket, lam,
         wk, _ = jax.lax.scan(step, w0, samples)
         return wk - w0
 
-    return jax.vmap(one_client)(bucket.idx, bucket.val, bucket.y, bucket.n_k,
-                                keys)
+    return map_clients(jax.vmap(one_client),
+                       (bucket.idx, bucket.val, bucket.y, bucket.n_k, keys),
+                       w0.shape[0])
 
 
 class DANE(FederatedSolver):
@@ -205,20 +208,12 @@ class DANE(FederatedSolver):
             use_kernel = jax.default_backend() == "tpu"
         lam = problem.flat.lam
         virtual = cfg.virtual_data or problem.virtual is not None
-        if virtual:
-            self._passes = []
-        elif cfg.local_solver == "gd":
-            self._passes = [
-                jax.jit(functools.partial(_dane_gd_pass, bucket=b, lam=lam,
-                                          cfg=cfg, use_kernel=use_kernel))
-                for b in problem.buckets
-            ]
+        if cfg.local_solver == "gd":
+            self._pass = jax.jit(functools.partial(
+                _dane_gd_pass, lam=lam, cfg=cfg, use_kernel=use_kernel))
         else:
-            self._passes = [
-                jax.jit(functools.partial(_dane_svrg_pass, bucket=b, lam=lam,
-                                          cfg=cfg))
-                for b in problem.buckets
-            ]
+            self._pass = jax.jit(functools.partial(_dane_svrg_pass, lam=lam,
+                                                   cfg=cfg))
         self.engine = RoundEngine(
             problem,
             EngineConfig(participation=cfg.participation, weighting="uniform",
@@ -236,7 +231,7 @@ class DANE(FederatedSolver):
         # Alg. 2 step 1's full gradient is the eager prelude (its own round
         # of communication); the rest of the round is one compiled dispatch.
         def dane_pass(w, bi, bucket, kb, full_grad):
-            return self._passes[bi](w, full_grad, key=kb)
+            return self._pass(w, full_grad, bucket, key=kb)
 
         if cfg.local_solver == "gd":
             def dane_chunk_pass(w, bi, chunk_bucket, keys, full_grad):

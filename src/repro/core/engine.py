@@ -125,7 +125,7 @@ the wire, not the client: dual state is whatever the honest pass computed.
 (per-client non-finite rejection + norm capping, folded into every path
 including the streamed chunk entries) or coordinate-wise
 ``"trimmed_mean"`` / ``"median"`` over the materialized delta stacks
-(``kernels/robust_aggregate``, plain and cohort paths only — the config
+(:func:`robust_aggregate`, plain and cohort paths only — the config
 rejects combinations whose stacks are never materialized).  With
 ``fault_model=None`` and ``aggregator_guard=None`` every path is
 bit-for-bit the pre-fault engine (no extra scan inputs, no extra traced
@@ -133,6 +133,7 @@ ops) — the parity the pin tests hold.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 import math
@@ -213,7 +214,7 @@ class EngineConfig:
     # per-client scalars, so they fold into the streamed fused_accumulate
     # chunk entries at O(chunk·d).  "trimmed_mean" / "median" ->
     # coordinate-wise order statistics over the valid (participating,
-    # all-finite) clients via kernels/robust_aggregate — a bounded fraction
+    # all-finite) clients via robust_aggregate — a bounded fraction
     # of adversarial deltas cannot move the aggregate arbitrarily.  Order
     # statistics need the materialized (K, d) stacks, so these are rejected
     # with client_chunk / virtual_data (the streamed body only ever holds
@@ -291,6 +292,47 @@ class EngineConfig:
 @functools.partial(jax.jit, static_argnames=("scaled",))
 def _apply_server_update(w, agg, a_diag, scaled: bool):
     return w + (a_diag if scaled else 1.0) * agg
+
+
+def robust_aggregate(w_t, deltas, valid, a_diag, trim=0.1,
+                     mode="trimmed_mean"):
+    """w^t + A ⊙ robust_agg({δ_k : valid_k}), in f32 — the order-statistic
+    server update behind ``EngineConfig.aggregator_guard``.
+
+    ``robust_agg`` is the coordinate-wise trimmed mean (drop the
+    ``trim``-fraction smallest and largest per coordinate, average the
+    rest) or median over the valid rows.  Invalid rows (non-participants,
+    guard-rejected non-finite deltas) are sorted past the rank window via
+    a +inf sentinel, so the dynamic valid count ``m`` sets the window and
+    one expression serves both modes (the median is the 1- or 2-rank
+    trimmed mean):
+
+        trimmed mean:  lo = floor(trim·m),  hi = m − lo
+        median:        lo = (m−1)//2,       hi = m//2 + 1
+
+    The sort is XLA's on every backend: Mosaic has no sort lowering, so
+    there is no Pallas kernel for this reduction."""
+    if mode not in _ORDER_STAT_GUARDS:
+        raise ValueError(f"mode must be one of {_ORDER_STAT_GUARDS}")
+    if not 0.0 <= trim < 0.5:
+        raise ValueError("trim must be in [0, 0.5)")
+    x = jnp.where(valid.reshape(-1, 1) > 0, deltas.astype(jnp.float32),
+                  jnp.inf)
+    xs = jnp.sort(x, axis=0)
+    m = valid.astype(jnp.int32).sum()
+    if mode == "median":
+        lo = (m - 1) // 2
+        hi = m // 2 + 1
+    else:
+        lo = jnp.floor(jnp.asarray(trim, jnp.float32)
+                       * m.astype(jnp.float32)).astype(jnp.int32)
+        hi = m - lo
+    ranks = jnp.arange(xs.shape[0])[:, None]
+    inc = (ranks >= lo) & (ranks < hi)
+    cnt = jnp.maximum(hi - lo, 1).astype(jnp.float32)
+    agg = jnp.where(inc, xs, 0.0).sum(axis=0) / cnt
+    agg = jnp.where(m > 0, agg, 0.0)                # empty round: no update
+    return w_t.astype(jnp.float32) + a_diag.astype(jnp.float32) * agg
 
 
 def _kernel(name: str) -> Callable:
@@ -460,13 +502,14 @@ class RoundEngine:
     def _robust_apply(self, w, deltas_all, valid):
         """Order-statistic server update over the stacked (K, d) deltas:
         rows that are invalid (non-participants) or carry any non-finite
-        coordinate are excluded, and the kernel's coordinate-wise trimmed
-        mean / median of the rest updates the iterate."""
+        coordinate are excluded, and the coordinate-wise trimmed mean /
+        median of the rest (:func:`robust_aggregate`) updates the
+        iterate."""
         finite = jnp.isfinite(deltas_all).all(axis=1)
         valid = valid & finite
         a = (self.a_diag if self.cfg.server_scaling == "diag"
              else jnp.ones_like(w))
-        return _kernel("robust_aggregate")(
+        return robust_aggregate(
             w, deltas_all, valid, a, self.cfg.guard_trim,
             self.cfg.aggregator_guard).astype(w.dtype)
 
@@ -1215,8 +1258,22 @@ class RoundEngine:
 
     # -- the compiled round: O(1) dispatches per round ---------------------- #
 
+    def _with_buckets(self, buckets) -> "RoundEngine":
+        """This engine over ``buckets`` in place of the problem's own.
+
+        The compiled rounds hand the bucket rows to their ``jax.jit`` as an
+        argument and run on this view of them: a jitted function embeds
+        every array it closes over as a program constant, and at the
+        paper's widths the rows are ~1 GB."""
+        view = copy.copy(self)
+        view.problem = dataclasses.replace(self.problem,
+                                           buckets=list(buckets))
+        return view
+
     def _should_donate(self, donate: Optional[bool]) -> bool:
-        # Donation is a no-op (with a warning) on CPU; default it off there.
+        # On by default on accelerators, where the iterate and state buffers
+        # are reused in place; off on CPU, where rounds are small and tests
+        # hand one array to several rounds.
         return jax.default_backend() != "cpu" if donate is None else donate
 
     def _require_chunk_pass(self, chunk_pass):
@@ -1283,43 +1340,49 @@ class RoundEngine:
         if self._use_cohort():
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, ctx, key, r):
-                return self.round_cohort(
+            def _round(eng, w, ctx, key, r):
+                return eng.round_cohort(
                     w, key,
                     lambda w_, bi, cb, ks: c_pass(w_, bi, cb, ks, *ctx),
                     round_index=r)
         elif self.cfg.client_chunk is not None:
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, ctx, key, r):
-                return self.round_streamed(
+            def _round(eng, w, ctx, key, r):
+                return eng.round_streamed(
                     w, key,
                     lambda w_, bi, cb, ks: c_pass(w_, bi, cb, ks, *ctx),
                     round_index=r)
         elif self.cfg.virtual_data:
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, ctx, key, r):
-                return self.round_virtual(
+            def _round(eng, w, ctx, key, r):
+                return eng.round_virtual(
                     w, key,
                     lambda w_, bi, cb, ks: c_pass(w_, bi, cb, ks, *ctx),
                     round_index=r)
         else:
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, ctx, key, r):
-                return self.round(
+            def _round(eng, w, ctx, key, r):
+                return eng.round(
                     w, key,
                     lambda w_, bi, b, kb: client_pass(w_, bi, b, kb, *ctx),
                     round_index=r)
 
-        def compiled_round(w, key, round_index=None):
-            ctx = tuple(prelude(w)) if prelude is not None else ()
-            return _body(w, ctx, key, self._round_index_arg(round_index))
+        @functools.partial(jax.jit, donate_argnums=donate_args)
+        def _body(w, ctx, key, r, buckets):
+            return _round(self._with_buckets(buckets), w, ctx, key, r)
 
+        def _args(w, key, round_index):
+            ctx = tuple(prelude(w)) if prelude is not None else ()
+            return (w, ctx, key, self._round_index_arg(round_index),
+                    tuple(self.problem.buckets))
+
+        def compiled_round(w, key, round_index=None):
+            return _body(*_args(w, key, round_index))
+
+        compiled_round.lower = lambda w, key, round_index=None: _body.lower(
+            *_args(w, key, round_index))
         return compiled_round
 
     def reference(self, client_pass: Callable, *,
@@ -1373,52 +1436,57 @@ class RoundEngine:
         if self._use_cohort():
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, states, ctx, key, r):
-                w2, new_states = self.round_cohort_with_state(
+            def _round(eng, w, states, ctx, key, r):
+                return eng.round_cohort_with_state(
                     w, list(states), key,
                     lambda w_, bi, cb, s_c, ks: c_pass(w_, bi, cb, s_c, ks,
                                                        *ctx),
                     round_index=r)
-                return w2, tuple(new_states)
         elif self.cfg.client_chunk is not None:
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, states, ctx, key, r):
-                w2, new_states = self.round_streamed_with_state(
+            def _round(eng, w, states, ctx, key, r):
+                return eng.round_streamed_with_state(
                     w, list(states), key,
                     lambda w_, bi, cb, s_c, ks: c_pass(w_, bi, cb, s_c, ks,
                                                        *ctx),
                     round_index=r)
-                return w2, tuple(new_states)
         elif self.cfg.virtual_data:
             c_pass = self._require_chunk_pass(chunk_pass)
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, states, ctx, key, r):
-                w2, new_states = self.round_virtual_with_state(
+            def _round(eng, w, states, ctx, key, r):
+                return eng.round_virtual_with_state(
                     w, list(states), key,
                     lambda w_, bi, cb, s_c, ks: c_pass(w_, bi, cb, s_c, ks,
                                                        *ctx),
                     round_index=r)
-                return w2, tuple(new_states)
         else:
 
-            @functools.partial(jax.jit, donate_argnums=donate_args)
-            def _body(w, states, ctx, key, r):
-                w2, new_states = self.round_with_state(
+            def _round(eng, w, states, ctx, key, r):
+                return eng.round_with_state(
                     w, list(states), key,
                     lambda w_, bi, b, s_b, kb: dual_pass(w_, bi, b, s_b, kb,
                                                          *ctx),
                     round_index=r)
-                return w2, tuple(new_states)
+
+        @functools.partial(jax.jit, donate_argnums=donate_args)
+        def _body(w, states, ctx, key, r, buckets):
+            w2, new_states = _round(self._with_buckets(buckets), w, states,
+                                    ctx, key, r)
+            return w2, tuple(new_states)
+
+        def _args(w, states, key, round_index):
+            ctx = tuple(prelude(w)) if prelude is not None else ()
+            return (w, tuple(states), ctx, key,
+                    self._round_index_arg(round_index),
+                    tuple(self.problem.buckets))
 
         def compiled_round(w, states, key, round_index=None):
-            ctx = tuple(prelude(w)) if prelude is not None else ()
-            return _body(w, tuple(states), ctx, key,
-                         self._round_index_arg(round_index))
+            return _body(*_args(w, states, key, round_index))
 
+        compiled_round.lower = (
+            lambda w, states, key, round_index=None: _body.lower(
+                *_args(w, states, key, round_index)))
         return compiled_round
 
     def reference_with_state(self, dual_pass: Callable, *,

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import EngineConfig, RoundEngine
-from repro.core.problem import ClientBucket, FederatedLogReg
+from repro.core.problem import ClientBucket, FederatedLogReg, map_clients
 from repro.core.registry import register
 from repro.core.solver import FederatedSolver, SolverState
 
@@ -111,7 +111,9 @@ def _local_sgd_pass_keyed(w0, bucket: ClientBucket, lam, cfg: FedAvgConfig,
         wk, _ = jax.lax.scan(epoch, w0, jax.random.split(ck, cfg.local_epochs))
         return wk - w0
 
-    return jax.vmap(one_client)(bucket.idx, bucket.val, bucket.y, bucket.n_k, keys)
+    return map_clients(jax.vmap(one_client),
+                       (bucket.idx, bucket.val, bucket.y, bucket.n_k, keys),
+                       w0.shape[0])
 
 
 class FedAvg(FederatedSolver):
@@ -127,12 +129,9 @@ class FedAvg(FederatedSolver):
         if use_kernel is None:
             use_kernel = jax.default_backend() == "tpu"
         virtual = cfg.virtual_data or problem.virtual is not None
-        self._passes = [] if virtual else [
-            jax.jit(functools.partial(_local_sgd_pass, bucket=b,
-                                      lam=problem.flat.lam, cfg=cfg,
-                                      use_kernel=use_kernel))
-            for b in problem.buckets
-        ]
+        self._pass = jax.jit(functools.partial(
+            _local_sgd_pass, lam=problem.flat.lam, cfg=cfg,
+            use_kernel=use_kernel))
         self.engine = RoundEngine(
             problem,
             EngineConfig(
@@ -151,7 +150,7 @@ class FedAvg(FederatedSolver):
         )
 
         def fedavg_pass(w, bi, bucket, kb):
-            return self._passes[bi](w, key=kb)
+            return self._pass(w, bucket, key=kb)
 
         def fedavg_chunk_pass(w, bi, chunk_bucket, keys):
             return _local_sgd_pass_keyed(w, chunk_bucket, problem.flat.lam,

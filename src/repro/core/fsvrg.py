@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from repro.core import scaling
 from repro.core.engine import EngineConfig, RoundEngine
-from repro.core.problem import ClientBucket, FederatedLogReg
+from repro.core.problem import ClientBucket, FederatedLogReg, map_clients
 from repro.core.registry import register
 from repro.core.solver import FederatedSolver, SolverState
 
@@ -129,7 +129,9 @@ def _client_pass_keyed(w0, full_grad, bucket: ClientBucket, lam, phi,
         wk, _ = jax.lax.scan(step, w0, samples)
         return wk - w0
 
-    return jax.vmap(one_client)(bucket.idx, bucket.val, bucket.y, bucket.n_k, keys)
+    return map_clients(jax.vmap(one_client),
+                       (bucket.idx, bucket.val, bucket.y, bucket.n_k, keys),
+                       w0.shape[0])
 
 
 class FSVRG(FederatedSolver):
@@ -148,12 +150,10 @@ class FSVRG(FederatedSolver):
         virtual = cfg.virtual_data or problem.virtual is not None
         self.phi = scaling.global_feature_counts(flat) / n
         self.a_diag = scaling.aggregation_diag(problem) if cfg.use_A else jnp.ones((problem.d,))
-        # virtual problems have no materialized buckets to close over — all
-        # round paths go through the keyed chunk pass instead
-        self._passes = [] if virtual else [
-            jax.jit(functools.partial(_client_pass, bucket=b, lam=flat.lam, cfg=cfg))
-            for b in problem.buckets
-        ]
+        # one jitted pass; the bucket is an argument (its rows are never
+        # closed over, so no compiled program embeds them)
+        self._pass = jax.jit(functools.partial(_client_pass, lam=flat.lam,
+                                               cfg=cfg))
         plain = cfg.naive  # Alg. 3: uniform aggregation, no A scaling
         self.engine = RoundEngine(
             problem,
@@ -178,7 +178,7 @@ class FSVRG(FederatedSolver):
         # dispatch.  The eager reference twin backs the pin tests and the
         # round-latency benchmark's baseline.
         def fsvrg_pass(w, bi, bucket, kb, full_grad):
-            return self._passes[bi](w, full_grad, phi=self.phi, key=kb)
+            return self._pass(w, full_grad, bucket, phi=self.phi, key=kb)
 
         def fsvrg_chunk_pass(w, bi, chunk_bucket, keys, full_grad):
             return _client_pass_keyed(w, full_grad, chunk_bucket, flat.lam,

@@ -42,11 +42,14 @@ def omega(problem) -> jax.Array:
     stream the count over regenerated chunks (exact, same integer sums)."""
     if getattr(problem, "virtual", None) is not None:
         return problem.flat.omega()
+    from repro.core.problem import map_clients
+
     d = problem.d
+    present = jax.vmap(lambda i, v: client_feature_counts(i, v, d) > 0)
     om = jnp.zeros((d,))
     for b in problem.buckets:
-        cc = jax.vmap(lambda i, v: client_feature_counts(i, v, d))(b.idx, b.val)
-        om = om + (cc > 0).sum(axis=0).astype(jnp.float32)
+        cc = map_clients(present, (b.idx, b.val), d)
+        om = om + cc.sum(axis=0).astype(jnp.float32)
     return om
 
 

@@ -84,8 +84,13 @@ class FederatedSolver:
 
         Dual methods whose iterate is a function of the dual state
         (Appendix-A Primal/Dual) override this and reject a custom ``w0``.
+
+        The state owns a copy of ``w0``: compiled rounds donate the
+        iterate buffer on accelerators, so a state built on the caller's
+        array would delete it for every later user (a second curve from
+        the same start point, a reference round from the same state).
         """
-        w0 = jnp.zeros((self.problem.d,)) if w0 is None else w0
+        w0 = jnp.zeros((self.problem.d,)) if w0 is None else jnp.array(w0)
         return SolverState(w=w0, aux=self._init_aux(w0),
                            round=jnp.asarray(0, jnp.int32))
 
@@ -96,6 +101,15 @@ class FederatedSolver:
 
     def round(self, state: SolverState, key: jax.Array) -> SolverState:
         raise NotImplementedError
+
+    def lower_round(self, state: SolverState, key: jax.Array):
+        """The ``jax.stages.Lowered`` program that :meth:`round` dispatches
+        from ``state`` — traced, not compiled or run.  Its ``as_text()``
+        shows what the compiler is handed (e.g. which Pallas kernels a
+        round calls); ``compile()`` compiles it — with JAX's persistent
+        cache on, that is the compile the first :meth:`round` loads."""
+        args = (state.w, state.aux) if state.aux else (state.w,)
+        return self._round_fast.lower(*args, key, round_index=state.round)
 
     # -- introspection ----------------------------------------------------- #
 

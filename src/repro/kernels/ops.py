@@ -12,7 +12,6 @@ from repro.kernels.cocoa_sdca import cocoa_sdca_update as _cocoa_sdca_update
 from repro.kernels.dane_update import dane_update as _dane_update
 from repro.kernels.fedavg_update import fedavg_update as _fedavg_update
 from repro.kernels.fsvrg_update import fsvrg_update as _fsvrg_update
-from repro.kernels.robust_aggregate import robust_aggregate as _robust_aggregate
 from repro.kernels.scaled_aggregate import fused_accumulate as _fused_accumulate
 from repro.kernels.scaled_aggregate import fused_aggregate as _fused_aggregate
 from repro.kernels.scaled_aggregate import fused_epilogue as _fused_epilogue
@@ -62,12 +61,6 @@ def fused_accumulate(acc, deltas, weights, **kw):
 def fused_epilogue(w_t, acc, a_diag, scale=1.0, **kw):
     kw.setdefault("interpret", not _on_tpu())
     return _fused_epilogue(w_t, acc, a_diag, scale, **kw)
-
-
-def robust_aggregate(w_t, deltas, valid, a_diag, trim=0.1,
-                     mode="trimmed_mean", **kw):
-    kw.setdefault("interpret", not _on_tpu())
-    return _robust_aggregate(w_t, deltas, valid, a_diag, trim, mode, **kw)
 
 
 def wkv6(r, k, v, w, u, **kw):

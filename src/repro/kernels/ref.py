@@ -92,37 +92,6 @@ def fused_epilogue_ref(w_t, acc, a_diag, scale=1.0):
                                             * acc.astype(jnp.float32)))
 
 
-def robust_aggregate_ref(w_t, deltas, valid, a_diag, trim=0.1,
-                         mode="trimmed_mean"):
-    """w^t + A ⊙ robust_agg({δ_k : valid_k}), in f32 — the order-statistic
-    oracle behind ``EngineConfig.aggregator_guard``.  ``robust_agg`` is the
-    coordinate-wise trimmed mean (drop the ``trim``-fraction smallest and
-    largest per coordinate, average the rest) or median over the valid
-    rows; invalid rows (non-participants, guard-rejected non-finite
-    deltas) are sorted past the rank window via a +inf sentinel, so the
-    dynamic valid count ``m`` sets the window and one expression serves
-    both modes (the median is the 1- or 2-rank trimmed mean)."""
-    if mode not in ("trimmed_mean", "median"):
-        raise ValueError("mode must be 'trimmed_mean' or 'median'")
-    x = jnp.where(valid.reshape(-1, 1) > 0, deltas.astype(jnp.float32),
-                  jnp.inf)
-    xs = jnp.sort(x, axis=0)
-    m = valid.astype(jnp.int32).sum()
-    if mode == "median":
-        lo = (m - 1) // 2
-        hi = m // 2 + 1
-    else:
-        lo = jnp.floor(jnp.asarray(trim, jnp.float32)
-                       * m.astype(jnp.float32)).astype(jnp.int32)
-        hi = m - lo
-    ranks = jnp.arange(xs.shape[0])[:, None]
-    inc = (ranks >= lo) & (ranks < hi)
-    cnt = jnp.maximum(hi - lo, 1).astype(jnp.float32)
-    agg = jnp.where(inc, xs, 0.0).sum(axis=0) / cnt
-    agg = jnp.where(m > 0, agg, 0.0)
-    return w_t.astype(jnp.float32) + a_diag.astype(jnp.float32) * agg
-
-
 def wkv6_ref(r, k, v, w, u):
     """Token-by-token WKV-6 recurrence in f32 — the oracle for the
     chunk-parallel ``kernels/wkv6.wkv6``.  Per (batch·head) pair with

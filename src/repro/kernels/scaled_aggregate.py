@@ -16,6 +16,11 @@ Grid: (d_blocks, k_blocks) — k is the *inner* (minor) dimension so each
 output tile stays resident in VMEM across the whole client reduction
 (revisiting-output accumulation pattern), accumulating in f32.
 
+Every operand is at least 2-D, as the TPU lowering wants: the (d,) vectors
+travel as (1, d) rows in (1, d_block) tiles, and the weights as a (K, 1)
+column whose (k_block, 1) tile follows the k grid index — each program
+sees exactly its own clients' weights, with no in-kernel slicing.
+
 :func:`scaled_aggregate` (the iterate-consuming entry point) survives as a
 thin compatibility wrapper; its pure-jnp oracle stays in ``kernels/ref.py``
 alongside the new :func:`~repro.kernels.ref.fused_aggregate_ref`.
@@ -33,16 +38,12 @@ K_BLOCK = 8
 D_BLOCK = 512
 
 
-def _fused_kernel(k_block, wt_ref, dk_ref, wts_ref, s_ref, a_ref, out_ref):
+def _fused_kernel(wt_ref, dk_ref, wts_ref, s_ref, a_ref, out_ref):
     kb = pl.program_id(1)
-    block_wts = jax.lax.dynamic_slice_in_dim(
-        wts_ref[...].reshape(-1), kb * k_block, k_block).astype(jnp.float32)
-    partial = jnp.einsum(
-        "kd,k->d",
-        dk_ref[...].astype(jnp.float32),
-        block_wts,
-        preferred_element_type=jnp.float32,
-    )
+    # (k_block, d_block) deltas × (k_block, 1) weights, summed over the
+    # client (sublane) axis in f32
+    partial = (dk_ref[...].astype(jnp.float32)
+               * wts_ref[...].astype(jnp.float32)).sum(axis=0, keepdims=True)
 
     @pl.when(kb == 0)
     def _init():
@@ -73,28 +74,30 @@ def fused_aggregate(w_t, deltas, weights, a_diag, scale=1.0, *,
     d_pad = -(-d // d_block) * d_block
     K_pad = -(-K // k_block) * k_block
 
-    wt2 = jnp.pad(w_t, (0, d_pad - d))
-    a2 = jnp.pad(a_diag, (0, d_pad - d))
+    def row(x):
+        return jnp.pad(x, (0, d_pad - d)).reshape(1, d_pad)
+
     dk2 = jnp.pad(deltas, ((0, K_pad - K), (0, d_pad - d)))
     wts2 = jnp.pad(weights, (0, K_pad - K)).reshape(K_pad, 1)
     s2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
 
     grid = (d_pad // d_block, K_pad // k_block)
+    vec = pl.BlockSpec((1, d_block), lambda i, k: (0, i))
     out = pl.pallas_call(
-        functools.partial(_fused_kernel, k_block),
+        _fused_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((d_block,), lambda i, k: (i,)),            # w_t
+            vec,                                                    # w_t
             pl.BlockSpec((k_block, d_block), lambda i, k: (k, i)),  # deltas
-            pl.BlockSpec((K_pad, 1), lambda i, k: (0, 0)),          # all weights
+            pl.BlockSpec((k_block, 1), lambda i, k: (k, 0)),        # weights
             pl.BlockSpec((1, 1), lambda i, k: (0, 0)),              # reweight s
-            pl.BlockSpec((d_block,), lambda i, k: (i,)),            # a_diag
+            vec,                                                    # a_diag
         ],
-        out_specs=pl.BlockSpec((d_block,), lambda i, k: (i,)),
-        out_shape=jax.ShapeDtypeStruct((d_pad,), jnp.float32),
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
         interpret=interpret,
-    )(wt2, dk2, wts2, s2, a2)
-    return out[:d]
+    )(row(w_t), dk2, wts2, s2, row(a_diag))
+    return out[0, :d]
 
 
 def fused_accumulate(acc, deltas, weights, **kw):

@@ -11,12 +11,20 @@ from __future__ import annotations
 import jax
 
 
+def _mesh(shape, axes):
+    # Auto axes: the sharding rules place arrays and let XLA propagate;
+    # jax.make_mesh's default Explicit axes would turn every
+    # with_sharding_constraint hint (sharding/hints.py) into an assertion
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1×1 mesh over the single CPU device (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))

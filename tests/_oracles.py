@@ -252,7 +252,7 @@ def fig2_fsvrg_loop(problem: FederatedLogReg, h: float, rounds: int,
         full_grad = problem.flat.grad(w)
 
         def fsvrg_pass(w_, bi, bucket, kb, fg=full_grad):
-            return solver._passes[bi](w_, fg, phi=solver.phi, key=kb)
+            return solver._pass(w_, fg, bucket, phi=solver.phi, key=kb)
 
         w = solver.engine.round(w, _round_key(seed, r), fsvrg_pass)
         hist.append(eval_fn(w))
@@ -272,7 +272,7 @@ def fig2_fedavg_loop(problem: FederatedLogReg, h: float, local_epochs: int,
         # verbatim pre-redesign FedAvg.round(w, key) body
         w = solver.engine.round(
             w, _round_key(seed, r),
-            lambda w_, bi, bucket, kb: solver._passes[bi](w_, key=kb))
+            lambda w_, bi, bucket, kb: solver._pass(w_, bucket, key=kb))
         hist.append(eval_fn(w))
     return w, hist
 
@@ -290,7 +290,7 @@ def fig2_dane_loop(problem: FederatedLogReg, rounds: int, seed: int, eval_fn,
         full_grad = problem.flat.grad(w)
 
         def dane_pass(w_, bi, bucket, kb, fg=full_grad):
-            return solver._passes[bi](w_, fg, key=kb)
+            return solver._pass(w_, fg, bucket, key=kb)
 
         w = solver.engine.round(w, _round_key(seed, r), dane_pass)
         hist.append(eval_fn(w))
@@ -310,10 +310,34 @@ def fig2_cocoa_loop(problem: FederatedLogReg, rounds: int, seed: int,
     for r in range(rounds):
         # verbatim pre-redesign CoCoAPlus.round(key) body, de-mutabilized
         def cocoa_pass(w_, bi, bucket, alpha_b, kb):
-            u, dr = solver._pass[bi](w_, alpha_b, kb)
+            u, dr = solver._pass(w_, alpha_b, bucket, kb)
             return dr * solver._scale, alpha_b + u
 
         w, alphas = solver.engine.round_with_state(
             w, alphas, _round_key(seed, r), cocoa_pass)
         hist.append(eval_fn(w))
     return w, alphas, hist
+
+
+# --------------------------------------------------------------------- #
+# order-statistic aggregation, one coordinate at a time in numpy
+# --------------------------------------------------------------------- #
+
+
+def order_stat_aggregate_np(w, deltas, valid, a_diag, trim, mode):
+    """w + a ⊙ (trimmed mean | median of the valid rows), per coordinate
+    in numpy: the plain reading of ``EngineConfig.aggregator_guard``.  The
+    trim rank is ``floor(trim·m)`` in f32, as the engine states it."""
+    import numpy as np
+
+    rows = np.asarray(deltas, np.float32)[np.asarray(valid) > 0]
+    m = rows.shape[0]
+    w = np.asarray(w, np.float32)
+    if m == 0:
+        return w
+    if mode == "median":
+        agg = np.median(rows, axis=0)
+    else:
+        lo = int(np.floor(np.float32(trim) * np.float32(m)))
+        agg = np.sort(rows, axis=0)[lo:m - lo].mean(axis=0)
+    return w + np.asarray(a_diag, np.float32) * agg

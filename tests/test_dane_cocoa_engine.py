@@ -240,3 +240,18 @@ def test_appendix_a_rejects_unequal_sizes(x64):
     with pytest.raises(ValueError):
         PrimalMethod(build_dense_problem(Xs, ys, 0.1), sigma=2.0,
                      alphas0=alphas0)
+
+
+def test_cocoa_dual_objective_ascends_and_bounds_the_primal(tiny_problem):
+    """CoCoA+ with the safe σ′ = K raises its dual D(α) every round (D = 0
+    at α = 0), and weak duality holds: D(α) ≤ f(w(α)).  The primal itself
+    need not fall below f(0) = log 2 in early rounds."""
+    solver = CoCoAPlus(tiny_problem)
+    state = solver.init()
+    dual = [float(solver.dual_objective(state))]
+    assert dual[0] == 0.0
+    for r in range(3):
+        state = solver.round(state, jax.random.PRNGKey(r))
+        dual.append(float(solver.dual_objective(state)))
+        assert dual[-1] <= float(tiny_problem.flat.loss(state.w)) + 1e-6
+    assert all(b > a for a, b in zip(dual, dual[1:])), dual

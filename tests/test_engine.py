@@ -25,7 +25,7 @@ def _prerefactor_fsvrg_round(problem, w, key, cfg, phi, a_diag, passes,
     expected_mass = jnp.zeros(())
     for b, pass_fn in zip(problem.buckets, passes):
         kb = jax.random.fold_in(key, wi)
-        deltas = pass_fn(w, full_grad, phi=phi, key=kb)
+        deltas = pass_fn(w, full_grad, b, phi=phi, key=kb)
         if cfg.naive or not cfg.use_weighted_agg:
             wts = jnp.full((b.num_clients,), 1.0 / problem.num_clients)
         else:
@@ -59,11 +59,12 @@ def test_fsvrg_on_engine_matches_prerefactor_trajectory(tiny_problem, participat
     cfg = FSVRGConfig(stepsize=1.0, participation=participation)
     solver = FSVRG(prob, cfg)
 
-    passes = [
-        jax.jit(functools.partial(_client_pass, bucket=b, lam=prob.flat.lam,
-                                  cfg=cfg))
-        for b in prob.buckets
-    ]
+    # the bucket rows are an argument of the jitted pass, as in the solver:
+    # closed-over rows would be program constants, which XLA may fold with
+    # a different rounding than the compiled pass
+    pass_fn = jax.jit(functools.partial(_client_pass, lam=prob.flat.lam,
+                                        cfg=cfg))
+    passes = [pass_fn] * len(prob.buckets)
     apply_fn = jax.jit(lambda w, agg, scale: w + scale * agg)
 
     state = solver.init()

@@ -437,7 +437,7 @@ def test_fault_counts_matches_kinds():
     assert int(inj0) == 0 and int(poi0) == 0
 
 
-@pytest.mark.parametrize("r", [0, 4])
+@pytest.mark.parametrize("r", [1, 4])
 def test_faulted_round_paths_parity(small_problem, r):
     """One fault model, three engine paths: plain, streamed, and cohort
     rounds corrupt the same clients identically (global-id draws, not
@@ -448,13 +448,22 @@ def test_faulted_round_paths_parity(small_problem, r):
     kw = dict(participation=TRACE.max_rate())
     eng = RoundEngine(prob, EngineConfig(**kw), participation_model=model,
                       fault_model=FAULTS)
+    key = jax.random.PRNGKey(70 + r)
+    # the round must corrupt a client that reports, or "the faults changed
+    # the round" below asserts nothing (round 0 of this trace and fault
+    # seed draws no fault on any of its three returned clients)
+    injected = sum(
+        int(fault_counts(FAULTS, r, eng._bucket_ids(wi, b.num_clients),
+                         m)[0])
+        for wi, b, m in zip(eng._offsets, prob.buckets,
+                            eng.participation_masks(key, r)))
+    assert injected > 0
     eng_ch = RoundEngine(prob, EngineConfig(client_chunk=3, **kw),
                          participation_model=model, fault_model=FAULTS)
     eng_co = RoundEngine(prob, EngineConfig(cohort=6, **kw),
                          participation_model=model, fault_model=FAULTS)
     client_pass, chunk_pass = _passes()
     w = jax.random.normal(jax.random.PRNGKey(1), (prob.d,)) * 0.1
-    key = jax.random.PRNGKey(70 + r)
     out = eng.round(w, key, client_pass, round_index=r)
     out_ch = eng_ch.round_streamed(w, key, chunk_pass, round_index=r)
     out_co = eng_co.round_cohort(w, key, chunk_pass, round_index=r)

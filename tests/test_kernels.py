@@ -9,6 +9,7 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dev dep; see requirements-dev.txt
 from hypothesis import given, settings, strategies as st
 
+import _oracles
 from repro.kernels import ops, ref
 
 
@@ -92,14 +93,17 @@ def test_scaled_aggregate_block_shapes(k_block, d_block):
     trim=st.floats(0.0, 0.49),
 )
 def test_robust_aggregate_matches_ref(K, d, seed, mode, trim):
+    from repro.core.engine import robust_aggregate
+
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     wt = jax.random.normal(ks[0], (d,))
     deltas = jax.random.normal(ks[1], (K, d))
     valid = jax.random.bernoulli(ks[2], 0.7, (K,))
     a = jnp.abs(jax.random.normal(ks[3], (d,))) + 0.5
-    out_k = ops.robust_aggregate(wt, deltas, valid, a, trim, mode)
-    out_r = ref.robust_aggregate_ref(wt, deltas, valid, a, trim, mode)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+    out = robust_aggregate(wt, deltas, valid, a, trim, mode)
+    expect = _oracles.order_stat_aggregate_np(wt, deltas, valid, a, trim,
+                                              mode)
+    np.testing.assert_allclose(np.asarray(out), expect,
                                rtol=1e-5, atol=1e-5)
 
 

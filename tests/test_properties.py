@@ -170,7 +170,14 @@ def test_property_D_identical_clients():
        st.integers(0, 10_000))
 def test_scaling_stats_invariants(K, nk, d, nnz, seed):
     rng = np.random.default_rng(seed)
-    clients = _random_clients(rng, K, nk, d, min(nnz, d))
+    # one entry per feature in a row, as the data generator emits (it zeroes
+    # repeats): with a repeat, n^j counts one example twice and φ^j > 1
+    clients = []
+    for idx, val, y in _random_clients(rng, K, nk, d, min(nnz, d)):
+        first = np.zeros(idx.shape, bool)
+        for r, row in enumerate(idx):
+            first[r, np.unique(row, return_index=True)[1]] = True
+        clients.append((idx, np.where(first, val, 0.0), y))
     prob = _dense_problem_from_clients(clients, d=d)
 
     om = np.asarray(scaling.omega(prob))

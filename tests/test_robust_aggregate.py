@@ -1,9 +1,9 @@
-"""robust_aggregate (the order-statistic aggregator-guard kernel) vs
-pure-numpy order statistics and the jnp oracle in kernels/ref.py.
+"""robust_aggregate (the order-statistic aggregator-guard update) vs
+pure-numpy order statistics.
 
 Deterministic sweeps only — unlike test_kernels.py this module must run
 without hypothesis (the aggregator guard is load-bearing for the fault-
-tolerance contract, so its parity coverage can't hinge on an optional dev
+tolerance contract, so its coverage can't hinge on an optional dev
 dependency); the hypothesis shape/seed sweep lives in test_kernels.py.
 """
 import jax
@@ -11,13 +11,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+import _oracles
+from repro.core.engine import robust_aggregate
 
 
 def test_robust_aggregate_matches_numpy_order_stats():
-    """Kernel AND ref against a plain numpy oracle: the median / trimmed
-    mean are taken over exactly the valid rows (odd count, so the median
-    is literally the middle element), scaled by a_diag."""
+    """The median / trimmed mean are taken over exactly the valid rows (odd
+    count, so the median is literally the middle element), scaled by
+    a_diag."""
     rng = np.random.default_rng(0)
     K, d = 9, 300
     deltas = rng.normal(size=(K, d)).astype(np.float32)
@@ -29,11 +30,10 @@ def test_robust_aggregate_matches_numpy_order_stats():
     # trim=0.2, m=7: lo = floor(0.2*7) = 1, hi = 7-1 = 6 -> mean of ranks 1..5
     expect_tm = w + a * np.sort(rows, axis=0)[1:6].mean(axis=0)
     for mode, expect in (("median", expect_med), ("trimmed_mean", expect_tm)):
-        for fn in (ops.robust_aggregate, ref.robust_aggregate_ref):
-            out = fn(jnp.asarray(w), jnp.asarray(deltas), jnp.asarray(valid),
-                     jnp.asarray(a), 0.2, mode)
-            np.testing.assert_allclose(np.asarray(out), expect,
-                                       rtol=1e-5, atol=1e-5)
+        out = robust_aggregate(jnp.asarray(w), jnp.asarray(deltas),
+                               jnp.asarray(valid), jnp.asarray(a), 0.2, mode)
+        np.testing.assert_allclose(np.asarray(out), expect,
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("K,d,trim,rate", [
@@ -41,17 +41,18 @@ def test_robust_aggregate_matches_numpy_order_stats():
     (24, 1000, 0.49, 0.3), (7, 4097, 0.1, 1.0),
 ])
 def test_robust_aggregate_matches_ref_across_shapes(K, d, trim, rate):
-    """Padding/grid edges (d below, at, and past d_block multiples) and
-    degenerate valid counts all agree with the jnp oracle."""
+    """Degenerate valid counts (none, one, all) and odd/even counts agree
+    with the per-coordinate numpy order statistics."""
     ks = jax.random.split(jax.random.PRNGKey(K * 7919 + d), 4)
     wt = jax.random.normal(ks[0], (d,))
     deltas = jax.random.normal(ks[1], (K, d))
     valid = jax.random.bernoulli(ks[2], rate, (K,))
     a = jnp.abs(jax.random.normal(ks[3], (d,))) + 0.5
     for mode in ("trimmed_mean", "median"):
-        out_k = ops.robust_aggregate(wt, deltas, valid, a, trim, mode)
-        out_r = ref.robust_aggregate_ref(wt, deltas, valid, a, trim, mode)
-        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+        out = robust_aggregate(wt, deltas, valid, a, trim, mode)
+        expect = _oracles.order_stat_aggregate_np(wt, deltas, valid, a,
+                                                  trim, mode)
+        np.testing.assert_allclose(np.asarray(out), expect,
                                    rtol=1e-5, atol=1e-5)
 
 
@@ -62,8 +63,7 @@ def test_robust_aggregate_all_invalid_is_identity():
     deltas = jnp.full((4, d), jnp.nan)
     valid = jnp.zeros((4,), jnp.int32)
     for mode in ("trimmed_mean", "median"):
-        out = ops.robust_aggregate(w, deltas, valid, jnp.ones((d,)),
-                                   0.1, mode)
+        out = robust_aggregate(w, deltas, valid, jnp.ones((d,)), 0.1, mode)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(w))
 
 
@@ -79,7 +79,7 @@ def test_robust_aggregate_bounds_poisoned_update():
     deltas[1] = -1e30
     w = np.zeros(d, np.float32)
     a = np.ones(d, np.float32)
-    out = np.asarray(ops.robust_aggregate(
+    out = np.asarray(robust_aggregate(
         jnp.asarray(w), jnp.asarray(deltas), jnp.ones((K,), jnp.int32),
         jnp.asarray(a), 0.2, "trimmed_mean"))
     honest = deltas[2:]
@@ -92,7 +92,6 @@ def test_robust_aggregate_validation():
     deltas = jnp.zeros((2, 8))
     valid = jnp.ones((2,), jnp.int32)
     with pytest.raises(ValueError, match="mode"):
-        ops.robust_aggregate(w, deltas, valid, jnp.ones(8), 0.1, "mean")
+        robust_aggregate(w, deltas, valid, jnp.ones(8), 0.1, "mean")
     with pytest.raises(ValueError, match="trim"):
-        ops.robust_aggregate(w, deltas, valid, jnp.ones(8), 0.5,
-                             "trimmed_mean")
+        robust_aggregate(w, deltas, valid, jnp.ones(8), 0.5, "trimmed_mean")

@@ -106,10 +106,7 @@ def test_sharded_train_step_runs_on_host_mesh():
     batch = make_batch(cfg, InputShape("t", 32, 4, "train"), dtype=jnp.float32)
     cb = make_client_batches(batch, num_clients=2, local_steps=1)
 
-    # jax.set_mesh only exists in newer jax; on 0.4.x the Mesh itself is the
-    # context manager (shardings below are explicit, the context is belt&braces)
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         in_sh = (rules.params_shardings(params, mesh),
                  rules.batch_shardings(cb, mesh, client_axis=True))
         step = jax.jit(make_fsvrg_round(model, FedNeuralConfig(stepsize=0.3)),
