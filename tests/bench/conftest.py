@@ -1,0 +1,42 @@
+"""The chip benchmark's CPU tests: small sizes, no chip.
+
+``benchmarks/chip`` goes on the import path, as ``run.py`` puts it there.
+"""
+import json
+import sys
+
+import pytest
+
+from bench_cases import CHIP, ROOT, TINY_PROBLEM
+
+if str(CHIP) not in sys.path:
+    sys.path.insert(0, str(CHIP))
+
+
+@pytest.fixture(scope="session")
+def tiny_root(tmp_path_factory):
+    """A root whose BENCHMARK.json is the repository's, with every
+    configuration's problem cut to ``TINY_PROBLEM``."""
+    root = tmp_path_factory.mktemp("tiny_root")
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    # the cohort mix, for the tests of the reference and the harness; its
+    # cells wait for limits measured on the chip (PERF.md)
+    for config in ("fedavg-gplus", "fsvrg-gplus"):
+        bench["workloads"].append({"name": f"{config}.cohort10",
+                                   "config": config, "traffic": "cohort10",
+                                   "chips": 1, "why": "tests only"})
+    for c in bench["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        cfg["problem"].update(TINY_PROBLEM)
+        c["file"] = f"{c['name']}.json"
+        (root / c["file"]).write_text(json.dumps(cfg))
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+@pytest.fixture(scope="session")
+def tiny_cell(tiny_root):
+    import catalog
+
+    bench = catalog.load(tiny_root)
+    return lambda name: catalog.Cell(bench, name, tiny_root)
