@@ -143,6 +143,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.problem import ClientBucket, FederatedLogReg, VirtualBucket
+from repro.utils import obs
 
 #: client_pass(w, bucket_index, bucket, key) -> (Kb, d) deltas w_k - w
 ClientPassFn = Callable[[jax.Array, int, ClientBucket, jax.Array], jax.Array]
@@ -335,6 +336,15 @@ def robust_aggregate(w_t, deltas, valid, a_diag, trim=0.1,
     return w_t.astype(jnp.float32) + a_diag.astype(jnp.float32) * agg
 
 
+def _prelude(prelude: Optional[Callable], w) -> tuple:
+    """The compiled round's eager per-round server state, ``prelude(w)``,
+    under the host span ``fl.prelude``; () without a prelude."""
+    if prelude is None:
+        return ()
+    with obs.span("fl.prelude"):
+        return tuple(prelude(w))
+
+
 def _kernel(name: str) -> Callable:
     """Resolve a delta-native aggregation kernel for this backend — the
     Pallas entry on TPU, the identical fused jnp oracle elsewhere (the same
@@ -462,6 +472,7 @@ class RoundEngine:
             return None
         return self._round_index_arg(round_index)
 
+    @obs.scoped("fl.fault")
     def _faulted(self, deltas, r, ids, live):
         """Corrupt the *returned* clients' deltas through the fault model.
 
@@ -482,6 +493,7 @@ class RoundEngine:
     def _order_stat(self) -> bool:
         return self.cfg.aggregator_guard in _ORDER_STAT_GUARDS
 
+    @obs.scoped("fl.guard")
     def _guard_clip(self, deltas):
         """The "clip" guard: reject (zero) any client delta with a
         non-finite coordinate, then cap the survivors' L2 norms.  Both are
@@ -499,6 +511,7 @@ class RoundEngine:
             safe = safe * fac.astype(safe.dtype)
         return safe
 
+    @obs.scoped("fl.guard")
     def _robust_apply(self, w, deltas_all, valid):
         """Order-statistic server update over the stacked (K, d) deltas:
         rows that are invalid (non-participants) or carry any non-finite
@@ -529,6 +542,7 @@ class RoundEngine:
                                    (num_clients,))
                 < self.cfg.participation).astype(jnp.float32)
 
+    @obs.scoped("fl.sample")
     def participation_masks(self, key: jax.Array,
                             round_index: Optional[Any] = None
                             ) -> Optional[List[jax.Array]]:
@@ -575,12 +589,14 @@ class RoundEngine:
         the materialized and streamed paths)."""
         return expected_mass / jnp.maximum(total_mass, 1e-9)
 
+    @obs.scoped("fl.aggregate")
     def _finish_dense(self, w, agg, scale):
         if scale is not None:
             agg = agg * scale
         return _apply_server_update(w, agg, self.a_diag,
                                     self.cfg.server_scaling == "diag")
 
+    @obs.scoped("fl.aggregate")
     def aggregate(self, w: jax.Array, deltas_by_bucket: Sequence[jax.Array],
                   key: jax.Array, *,
                   masks: Optional[Sequence[jax.Array]] = None) -> jax.Array:
@@ -665,8 +681,9 @@ class RoundEngine:
         r = self._fault_round(round_index)
         deltas: List[jax.Array] = []
         for bi, (wi, b) in enumerate(zip(self._offsets, self.problem.buckets)):
-            kb = jax.random.fold_in(key, wi)
-            d_b = client_pass(w, bi, self._realize(b), kb)
+            with obs.scope("fl.client_pass"):
+                kb = jax.random.fold_in(key, wi)
+                d_b = client_pass(w, bi, self._realize(b), kb)
             if self.fault_model is not None:
                 d_b = self._faulted(d_b, r, self._bucket_ids(wi, b.num_clients),
                                     masks[bi] if masks is not None else None)
@@ -697,8 +714,10 @@ class RoundEngine:
         deltas: List[jax.Array] = []
         new_states: List[Any] = []
         for bi, (wi, b) in enumerate(zip(self._offsets, self.problem.buckets)):
-            kb = jax.random.fold_in(key, wi)
-            d_b, s_b = client_pass(w, bi, self._realize(b), states[bi], kb)
+            with obs.scope("fl.client_pass"):
+                kb = jax.random.fold_in(key, wi)
+                d_b, s_b = client_pass(w, bi, self._realize(b), states[bi],
+                                       kb)
             if self.fault_model is not None:
                 # the wire, not the client: the delta is corrupted, the
                 # client's own aux state is whatever its pass computed
@@ -706,11 +725,13 @@ class RoundEngine:
                                     masks[bi] if masks is not None else None)
             if masks is not None:
                 sel = masks[bi]
-                s_b = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(
-                        sel.reshape((b.num_clients,) + (1,) * (new.ndim - 1))
-                        > 0, new, old),
-                    s_b, states[bi])
+                with obs.scope("fl.client_pass"):
+                    s_b = jax.tree_util.tree_map(
+                        lambda new, old: jnp.where(
+                            sel.reshape((b.num_clients,)
+                                        + (1,) * (new.ndim - 1)) > 0,
+                            new, old),
+                        s_b, states[bi])
             deltas.append(d_b)
             new_states.append(s_b)
         return self.aggregate(w, deltas, key, masks=masks), new_states
@@ -803,31 +824,35 @@ class RoundEngine:
         m_pad = bucket.m_pad
 
         def body(acc, x):
-            if virtual:
-                cb = self._virtual.materialize(x["cid"], x["n_k"], m_pad)
-            else:
-                cb = ClientBucket(x["idx"], x["val"], x["y"], x["n_k"])
-            if state_b is None:
-                deltas = chunk_pass(w, bi, cb, x["keys"])
-                s_new = None
-            else:
-                deltas, s_new = chunk_pass(w, bi, cb, x["state"], x["keys"])
-                if sel is not None:
-                    s_new = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(
-                            x["sel"].reshape((chunk,) + (1,) * (new.ndim - 1))
-                            > 0, new, old),
-                        s_new, x["state"])
+            with obs.scope("fl.client_pass"):
+                if virtual:
+                    cb = self._virtual.materialize(x["cid"], x["n_k"], m_pad)
+                else:
+                    cb = ClientBucket(x["idx"], x["val"], x["y"], x["n_k"])
+                if state_b is None:
+                    deltas = chunk_pass(w, bi, cb, x["keys"])
+                    s_new = None
+                else:
+                    deltas, s_new = chunk_pass(w, bi, cb, x["state"],
+                                               x["keys"])
+                    if sel is not None:
+                        s_new = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(
+                                x["sel"].reshape((chunk,)
+                                                 + (1,) * (new.ndim - 1))
+                                > 0, new, old),
+                            s_new, x["state"])
             if self.fault_model is not None:
                 # live = the chunk's (already sel-zeroed) weights: only
                 # clients actually contributing to the sum can be faulted
                 deltas = self._faulted(deltas, r, x["ids"], x["wts"])
             deltas = self._guard_clip(deltas)
-            if fused:
-                # the kernel's init/acc split with an identity epilogue
-                acc = _kernel("fused_accumulate")(acc, deltas, x["wts"])
-            else:
-                acc = acc + (x["wts"][:, None] * deltas).sum(axis=0)
+            with obs.scope("fl.aggregate"):
+                if fused:
+                    # the kernel's init/acc split with an identity epilogue
+                    acc = _kernel("fused_accumulate")(acc, deltas, x["wts"])
+                else:
+                    acc = acc + (x["wts"][:, None] * deltas).sum(axis=0)
             return acc, s_new
 
         acc, s_stack = jax.lax.scan(body, jnp.zeros_like(w), xs)
@@ -843,7 +868,6 @@ class RoundEngine:
         # _masked_bucket, which streams when cfg.client_chunk is set and
         # otherwise runs the direct keyed pass over the (realized) bucket —
         # so this one body serves round_streamed AND round_virtual.
-        cfg = self.cfg
         r = self._fault_round(round_index)
         reweight = self._reweightable(masks)
         acc = jnp.zeros_like(w)
@@ -852,32 +876,41 @@ class RoundEngine:
         new_states: Optional[List[Any]] = [] if states is not None else None
         for bi, (wi, b) in enumerate(zip(self._offsets, self.problem.buckets)):
             kb = jax.random.fold_in(key, wi)
-            wts = self.bucket_weights(wi, b.num_clients)
             sel = masks[bi] if masks is not None else None
-            if sel is not None:
-                if reweight:
-                    total_mass = total_mass + (wts * sel).sum()
-                    expected_mass = expected_mass + wts.sum()
-                wts = wts * sel
-            acc_b, s_b = self._masked_bucket(
-                w, bi, b, kb, self.client_keys(kb, b.num_clients), wts, sel,
-                chunk_pass,
-                state_b=states[bi] if states is not None else None,
-                ids=(self._bucket_ids(wi, b.num_clients)
-                     if self.fault_model is not None else None), r=r)
-            acc = acc + acc_b
+            with obs.scope("fl.aggregate"):
+                wts = self.bucket_weights(wi, b.num_clients)
+                if sel is not None:
+                    if reweight:
+                        total_mass = total_mass + (wts * sel).sum()
+                        expected_mass = expected_mass + wts.sum()
+                    wts = wts * sel
+            with obs.scope("fl.client_pass"):
+                acc_b, s_b = self._masked_bucket(
+                    w, bi, b, kb, self.client_keys(kb, b.num_clients), wts,
+                    sel, chunk_pass,
+                    state_b=states[bi] if states is not None else None,
+                    ids=(self._bucket_ids(wi, b.num_clients)
+                         if self.fault_model is not None else None), r=r)
+            with obs.scope("fl.aggregate"):
+                acc = acc + acc_b
             if new_states is not None:
                 new_states.append(s_b)
+        return self._epilogue(w, acc, total_mass, expected_mass,
+                              reweight), new_states
+
+    @obs.scoped("fl.aggregate")
+    def _epilogue(self, w, acc, total_mass, expected_mass, reweight):
+        """The server update from the round's summed weighted deltas: the
+        reweight scalar and the A scaling, folded into the kernel's
+        epilogue under ``aggregator="pallas"``."""
         scale = self._reweight_scale(total_mass, expected_mass) \
             if reweight else None
-
-        if cfg.aggregator == "pallas":
-            a = self.a_diag if cfg.server_scaling == "diag" else jnp.ones_like(w)
+        if self.cfg.aggregator == "pallas":
+            a = (self.a_diag if self.cfg.server_scaling == "diag"
+                 else jnp.ones_like(w))
             s = scale if scale is not None else 1.0
-            w_next = _kernel("fused_epilogue")(w, acc, a, s).astype(w.dtype)
-        else:
-            w_next = self._finish_dense(w, acc, scale)
-        return w_next, new_states
+            return _kernel("fused_epilogue")(w, acc, a, s).astype(w.dtype)
+        return self._finish_dense(w, acc, scale)
 
     def round_streamed(self, w: jax.Array, key: jax.Array,
                        chunk_pass: ChunkClientPassFn, *,
@@ -952,6 +985,7 @@ class RoundEngine:
 
     # -- the cohort round: O(participation · K) client passes --------------- #
 
+    @obs.scoped("fl.aggregate")
     def _bucket_accumulate(self, w, deltas, wts):
         """One bucket's weighted delta sum as a (d,) vector — the fused
         kernel's accumulate entry under ``aggregator="pallas"``, the plain
@@ -1022,31 +1056,35 @@ class RoundEngine:
             return self._masked_bucket(w, bi, bucket, kb, keys, wtsz, sel,
                                        chunk_pass, state_b=state_b,
                                        ids=ids, r=r)
-        count = jnp.count_nonzero(sel > 0)
+        with obs.scope("fl.gather"):
+            count = jnp.count_nonzero(sel > 0)
 
         def cohort_branch(_):
-            gidx = jnp.nonzero(sel > 0, size=cap, fill_value=0)[0]
-            valid = jnp.arange(cap) < count
-            if self._virtual is not None and isinstance(bucket, VirtualBucket):
-                # gather only the cohort's *identities*; their rows are
-                # regenerated below (realize / the streamed body) — data is
-                # only ever produced for the O(cap) sampled clients
-                g_bucket = VirtualBucket(
-                    bucket.client_ids[gidx],
-                    jnp.where(valid, bucket.n_k[gidx], 0), bucket.m_pad)
-            else:
-                g_bucket = ClientBucket(bucket.idx[gidx], bucket.val[gidx],
-                                        bucket.y[gidx],
-                                        jnp.where(valid, bucket.n_k[gidx], 0))
-            g_keys = keys[gidx]
-            g_wts = jnp.where(valid, wtsz[gidx], 0.0)
-            # gathered global ids: fault draws fold in the client's original
-            # identity, so the cohort corrupts exactly the clients the
-            # masked path would (pad rows alias ids[0] but carry weight 0,
-            # so _faulted leaves them honest)
-            g_ids = ids[gidx] if self.fault_model is not None else None
-            g_state = None if state_b is None else jax.tree_util.tree_map(
-                lambda a: a[gidx], state_b)
+            with obs.scope("fl.gather"):
+                gidx = jnp.nonzero(sel > 0, size=cap, fill_value=0)[0]
+                valid = jnp.arange(cap) < count
+                if (self._virtual is not None
+                        and isinstance(bucket, VirtualBucket)):
+                    # gather only the cohort's *identities*; their rows are
+                    # regenerated below (realize / the streamed body) —
+                    # data is only ever produced for the O(cap) sampled
+                    # clients
+                    g_bucket = VirtualBucket(
+                        bucket.client_ids[gidx],
+                        jnp.where(valid, bucket.n_k[gidx], 0), bucket.m_pad)
+                else:
+                    g_bucket = ClientBucket(
+                        bucket.idx[gidx], bucket.val[gidx], bucket.y[gidx],
+                        jnp.where(valid, bucket.n_k[gidx], 0))
+                g_keys = keys[gidx]
+                g_wts = jnp.where(valid, wtsz[gidx], 0.0)
+                # gathered global ids: fault draws fold in the client's
+                # original identity, so the cohort corrupts exactly the
+                # clients the masked path would (pad rows alias ids[0] but
+                # carry weight 0, so _faulted leaves them honest)
+                g_ids = ids[gidx] if self.fault_model is not None else None
+                g_state = None if state_b is None else jax.tree_util.tree_map(
+                    lambda a: a[gidx], state_b)
             if self.cfg.client_chunk is not None:
                 acc_b, s_new = self._stream_bucket(
                     w, bi, g_bucket, kb, g_wts, chunk_pass,
@@ -1071,10 +1109,11 @@ class RoundEngine:
             # padding rows target index Kb — out of bounds, dropped — and
             # non-gathered clients keep their old state (frozen).  Valid
             # gidx entries are unique, so the scatter is deterministic.
-            scatter_idx = jnp.where(valid, gidx, Kb)
-            new_state = jax.tree_util.tree_map(
-                lambda old, new: old.at[scatter_idx].set(new, mode="drop"),
-                state_b, s_new)
+            with obs.scope("fl.gather"):
+                scatter_idx = jnp.where(valid, gidx, Kb)
+                new_state = jax.tree_util.tree_map(
+                    lambda old, new: old.at[scatter_idx].set(new, mode="drop"),
+                    state_b, s_new)
             return acc_b, new_state
 
         def masked_branch(_):
@@ -1094,7 +1133,6 @@ class RoundEngine:
         if self._order_stat():
             return self._cohort_round_robust(w, key, chunk_pass, states,
                                              masks, round_index=round_index)
-        cfg = self.cfg
         r = self._fault_round(round_index)
         reweight = self._reweightable(masks)
         acc = jnp.zeros_like(w)
@@ -1103,29 +1141,24 @@ class RoundEngine:
         new_states: Optional[List[Any]] = [] if states is not None else None
         for bi, (wi, b) in enumerate(zip(self._offsets, self.problem.buckets)):
             kb = jax.random.fold_in(key, wi)
-            wts = self.bucket_weights(wi, b.num_clients)
             sel = masks[bi] if masks is not None else None
-            if sel is not None and reweight:
-                total_mass = total_mass + (wts * sel).sum()
-                expected_mass = expected_mass + wts.sum()
-            acc_b, s_b = self._cohort_bucket(
-                w, bi, b, kb, wts, sel, chunk_pass,
-                state_b=states[bi] if states is not None else None,
-                ids=(self._bucket_ids(wi, b.num_clients)
-                     if self.fault_model is not None else None), r=r)
-            acc = acc + acc_b
+            with obs.scope("fl.aggregate"):
+                wts = self.bucket_weights(wi, b.num_clients)
+                if sel is not None and reweight:
+                    total_mass = total_mass + (wts * sel).sum()
+                    expected_mass = expected_mass + wts.sum()
+            with obs.scope("fl.client_pass"):
+                acc_b, s_b = self._cohort_bucket(
+                    w, bi, b, kb, wts, sel, chunk_pass,
+                    state_b=states[bi] if states is not None else None,
+                    ids=(self._bucket_ids(wi, b.num_clients)
+                         if self.fault_model is not None else None), r=r)
+            with obs.scope("fl.aggregate"):
+                acc = acc + acc_b
             if new_states is not None:
                 new_states.append(s_b)
-        scale = self._reweight_scale(total_mass, expected_mass) \
-            if reweight else None
-
-        if cfg.aggregator == "pallas":
-            a = self.a_diag if cfg.server_scaling == "diag" else jnp.ones_like(w)
-            s = scale if scale is not None else 1.0
-            w_next = _kernel("fused_epilogue")(w, acc, a, s).astype(w.dtype)
-        else:
-            w_next = self._finish_dense(w, acc, scale)
-        return w_next, new_states
+        return self._epilogue(w, acc, total_mass, expected_mass,
+                              reweight), new_states
 
     def _cohort_round_robust(self, w, key, chunk_pass, states, masks, *,
                              round_index=None):
@@ -1154,9 +1187,10 @@ class RoundEngine:
         valids: List[jax.Array] = []
         new_states: Optional[List[Any]] = [] if states is not None else None
         for bi, (wi, b) in enumerate(zip(self._offsets, self.problem.buckets)):
-            kb = jax.random.fold_in(key, wi)
             Kb = b.num_clients
-            keys = self.client_keys(kb, Kb)
+            with obs.scope("fl.client_pass"):
+                kb = jax.random.fold_in(key, wi)
+                keys = self.client_keys(kb, Kb)
             sel = masks[bi] if masks is not None else None
             ids = (self._bucket_ids(wi, Kb)
                    if self.fault_model is not None else None)
@@ -1166,59 +1200,69 @@ class RoundEngine:
                       if self.cfg.participation < 1.0 else Kb)
             if sel is None or cap >= Kb:
                 # degenerate case: the full keyed pass, whole-bucket stack
-                bucket = self._realize(b)
-                if state_b is None:
-                    deltas = chunk_pass(w, bi, bucket, keys)
-                    s_new = None
-                else:
-                    deltas, s_new = chunk_pass(w, bi, bucket, state_b, keys)
-                    if sel is not None:
-                        s_new = jax.tree_util.tree_map(
-                            lambda new, old: jnp.where(
-                                sel.reshape((Kb,) + (1,) * (new.ndim - 1))
-                                > 0, new, old),
-                            s_new, state_b)
+                with obs.scope("fl.client_pass"):
+                    bucket = self._realize(b)
+                    if state_b is None:
+                        deltas = chunk_pass(w, bi, bucket, keys)
+                        s_new = None
+                    else:
+                        deltas, s_new = chunk_pass(w, bi, bucket, state_b,
+                                                   keys)
+                        if sel is not None:
+                            s_new = jax.tree_util.tree_map(
+                                lambda new, old: jnp.where(
+                                    sel.reshape((Kb,) + (1,) * (new.ndim - 1))
+                                    > 0, new, old),
+                                s_new, state_b)
                 if self.fault_model is not None:
                     deltas = self._faulted(deltas, r, ids, sel)
                 stacks.append(deltas)
-                valids.append(sel > 0 if sel is not None
-                              else jnp.ones((Kb,), bool))
+                with obs.scope("fl.aggregate"):
+                    valids.append(sel > 0 if sel is not None
+                                  else jnp.ones((Kb,), bool))
                 if new_states is not None:
                     new_states.append(s_new)
                 continue
-            count = jnp.count_nonzero(sel > 0)
-            gidx = jnp.nonzero(sel > 0, size=cap, fill_value=0)[0]
-            gvalid = jnp.arange(cap) < count
-            if self._virtual is not None and isinstance(b, VirtualBucket):
-                g_bucket = VirtualBucket(
-                    b.client_ids[gidx],
-                    jnp.where(gvalid, b.n_k[gidx], 0), b.m_pad)
-            else:
-                g_bucket = ClientBucket(b.idx[gidx], b.val[gidx],
-                                        b.y[gidx],
-                                        jnp.where(gvalid, b.n_k[gidx], 0))
-            g_keys = keys[gidx]
-            g_ids = ids[gidx] if ids is not None else None
-            if state_b is None:
-                deltas = chunk_pass(w, bi, self._realize(g_bucket), g_keys)
-                s_new = None
-            else:
-                g_state = jax.tree_util.tree_map(lambda a: a[gidx], state_b)
-                deltas, s_new = chunk_pass(w, bi, self._realize(g_bucket),
-                                           g_state, g_keys)
+            with obs.scope("fl.gather"):
+                count = jnp.count_nonzero(sel > 0)
+                gidx = jnp.nonzero(sel > 0, size=cap, fill_value=0)[0]
+                gvalid = jnp.arange(cap) < count
+                if self._virtual is not None and isinstance(b, VirtualBucket):
+                    g_bucket = VirtualBucket(
+                        b.client_ids[gidx],
+                        jnp.where(gvalid, b.n_k[gidx], 0), b.m_pad)
+                else:
+                    g_bucket = ClientBucket(b.idx[gidx], b.val[gidx],
+                                            b.y[gidx],
+                                            jnp.where(gvalid, b.n_k[gidx], 0))
+                g_keys = keys[gidx]
+                g_ids = ids[gidx] if ids is not None else None
+            with obs.scope("fl.client_pass"):
+                if state_b is None:
+                    deltas = chunk_pass(w, bi, self._realize(g_bucket),
+                                        g_keys)
+                    s_new = None
+                else:
+                    with obs.scope("fl.gather"):
+                        g_state = jax.tree_util.tree_map(lambda a: a[gidx],
+                                                         state_b)
+                    deltas, s_new = chunk_pass(w, bi, self._realize(g_bucket),
+                                               g_state, g_keys)
             if self.fault_model is not None:
                 deltas = self._faulted(deltas, r, g_ids,
                                        gvalid.astype(jnp.float32))
             stacks.append(deltas)
             valids.append(gvalid)
             if new_states is not None:
-                scatter_idx = jnp.where(gvalid, gidx, Kb)
-                new_states.append(jax.tree_util.tree_map(
-                    lambda old, new: old.at[scatter_idx].set(new,
-                                                             mode="drop"),
-                    state_b, s_new))
-        w_next = self._robust_apply(w, jnp.concatenate(stacks, axis=0),
-                                    jnp.concatenate(valids))
+                with obs.scope("fl.gather"):
+                    scatter_idx = jnp.where(gvalid, gidx, Kb)
+                    new_states.append(jax.tree_util.tree_map(
+                        lambda old, new: old.at[scatter_idx].set(
+                            new, mode="drop"),
+                        state_b, s_new))
+        with obs.scope("fl.aggregate"):
+            w_next = self._robust_apply(w, jnp.concatenate(stacks, axis=0),
+                                        jnp.concatenate(valids))
         return w_next, new_states
 
     def round_cohort(self, w: jax.Array, key: jax.Array,
@@ -1374,12 +1418,14 @@ class RoundEngine:
             return _round(self._with_buckets(buckets), w, ctx, key, r)
 
         def _args(w, key, round_index):
-            ctx = tuple(prelude(w)) if prelude is not None else ()
-            return (w, ctx, key, self._round_index_arg(round_index),
+            return (w, _prelude(prelude, w), key,
+                    self._round_index_arg(round_index),
                     tuple(self.problem.buckets))
 
         def compiled_round(w, key, round_index=None):
-            return _body(*_args(w, key, round_index))
+            args = _args(w, key, round_index)
+            with obs.span("fl.dispatch"):
+                return _body(*args)
 
         compiled_round.lower = lambda w, key, round_index=None: _body.lower(
             *_args(w, key, round_index))
@@ -1476,13 +1522,14 @@ class RoundEngine:
             return w2, tuple(new_states)
 
         def _args(w, states, key, round_index):
-            ctx = tuple(prelude(w)) if prelude is not None else ()
-            return (w, tuple(states), ctx, key,
+            return (w, tuple(states), _prelude(prelude, w), key,
                     self._round_index_arg(round_index),
                     tuple(self.problem.buckets))
 
         def compiled_round(w, states, key, round_index=None):
-            return _body(*_args(w, states, key, round_index))
+            args = _args(w, states, key, round_index)
+            with obs.span("fl.dispatch"):
+                return _body(*args)
 
         compiled_round.lower = (
             lambda w, states, key, round_index=None: _body.lower(

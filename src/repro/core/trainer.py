@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.solver import FederatedSolver, SolverState
+from repro.utils import obs
 
 EvalFn = Callable[[jax.Array], Dict[str, Any]]
 
@@ -176,20 +177,26 @@ class Trainer:
         history: List[Dict[str, float]] = []
         saved_at = -1
         for r in range(start, self.rounds):
-            state = self.solver.round(state, jax.random.fold_in(base, r))
-            self._check_finite(state, r)
-            if self.eval_fn is not None and self._is_eval_round(r):
-                history.append({k: float(v)
-                                for k, v in self.eval_fn(state.w).items()})
-            if self.callback is not None:
-                self.callback(state, r)
-            if (self.checkpoint_every
-                    and (r + 1) % self.checkpoint_every == 0):
-                self.save(state)
-                saved_at = r + 1
+            with obs.round_span(r):
+                state = self.solver.round(state, jax.random.fold_in(base, r))
+                with obs.span("fl.check_finite"):
+                    self._check_finite(state, r)
+                if self.eval_fn is not None and self._is_eval_round(r):
+                    with obs.span("fl.eval"):
+                        history.append({k: float(v) for k, v in
+                                        self.eval_fn(state.w).items()})
+                if self.callback is not None:
+                    with obs.span("fl.callback"):
+                        self.callback(state, r)
+                if (self.checkpoint_every
+                        and (r + 1) % self.checkpoint_every == 0):
+                    with obs.span("fl.checkpoint"):
+                        self.save(state)
+                    saved_at = r + 1
         # the saved checkpoint must never lag the returned result
         if self.checkpoint_dir and saved_at != self.rounds:
-            self.save(state)
+            with obs.span("fl.checkpoint"):
+                self.save(state)
         return FitResult(state=state, history=history, solver=self.solver)
 
     def _fit_scan(self, state: SolverState, start: int) -> FitResult:
@@ -218,8 +225,9 @@ class Trainer:
                 metrics = self.eval_fn(s.w) if self.eval_fn is not None else {}
             return s, metrics
 
-        final, stacked = jax.jit(
-            lambda s, xs: jax.lax.scan(body, s, xs))(state, (rs, keys))
+        with obs.span("fl.scan"):
+            final, stacked = jax.jit(
+                lambda s, xs: jax.lax.scan(body, s, xs))(state, (rs, keys))
         self._check_finite(final, self.rounds - 1)
         if self.eval_fn is None:
             history: List[Dict[str, float]] = []
