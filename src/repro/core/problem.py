@@ -44,8 +44,16 @@ def pad_rows(idx, val):
     return xp.pad(idx, widths), xp.pad(val, widths)
 
 
-#: Largest dense (clients, d) block a client pass materializes at once.
-CLIENT_BLOCK_ELEMS = 1 << 20
+#: Largest dense (clients, d) block a client pass materializes at once:
+#: 64 clients at the §4 width d = 20,002, chosen from a sweep of 32 to 256
+#: on a TPU v5e.  A sequential step of a client pass costs about 55 µs up
+#: to ~32 clients, and beyond that about 1.7 µs (FedAvg) to 2.4 µs (FSVRG)
+#: a client, more still past 64 (128 clients: 2.3 and 2.5 µs).  So 64
+#: cuts the short last batches of the small buckets at the same cost a
+#: client, while 128 made the FedAvg round 25% slower.  The round's temp
+#: grows with the block (+32 MB of 1.97 GB for the FSVRG round at 64
+#: clients), and the TPU compiler's time for a scatter with its operand.
+CLIENT_BLOCK_ELEMS = 1 << 21
 
 
 def client_batch(d: int) -> int:
@@ -62,9 +70,13 @@ def map_clients(fn, xs, d: int):
     clients to outputs with the same leading axis and must treat clients
     independently, so the result is ``fn(*xs)`` over the whole axis.
 
-    Client passes build dense (clients, d) vectors by scatter, and the TPU
-    compiler's time for a scatter grows with its operand: 15 s for one
-    6,478-client bucket at d = 20,002, under 1 s for 32 clients."""
+    Every sparse solver's client pass runs through here (FSVRG, FedAvg,
+    DANE, CoCoA+, the GD baseline), as does ``scaling.omega``.  The batches
+    run one after another.  Client passes build dense (clients, d) vectors
+    by scatter, so a batch's local step costs time in proportion to its
+    clients (above a floor), and the round's temp memory and the TPU
+    compiler's time for a scatter grow with that operand (15 s for one
+    6,478-client bucket at d = 20,002, under 1 s for 32 clients)."""
     n = jax.tree_util.tree_leaves(xs)[0].shape[0]
     batch = client_batch(d)
     if n <= batch:

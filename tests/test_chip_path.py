@@ -5,7 +5,8 @@
 * a state owns its iterate, so buffer donation never deletes the caller's
   ``w0``;
 * client passes run over client batches (``map_clients``) with results
-  equal to one vmap over the bucket;
+  equal to one vmap over the bucket, FSVRG's and FedAvg's passes too; the
+  §4 round's count of sequential steps at the chosen batch;
 * sparse rows are stored ``row_width`` wide, and the padding changes no
   objective value;
 * scripts' compile cache: the environment's directory wins, else the
@@ -99,12 +100,89 @@ def test_map_clients_equals_one_vmap(monkeypatch, n):
                                       np.asarray(want[k]))
 
 
+def _bucket(kb, m_pad, width, d, seed=0):
+    """``kb`` clients of random sparse rows, n_k in [1, m_pad]."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    n_k = jax.random.randint(ks[0], (kb,), 1, m_pad + 1)
+    live = jnp.arange(m_pad)[None, :] < n_k[:, None]
+    idx = jax.random.randint(ks[1], (kb, m_pad, width), 0, d)
+    val = jax.random.uniform(ks[2], (kb, m_pad, width)) * live[..., None]
+    y = jnp.where(jax.random.bernoulli(ks[3], 0.5, (kb, m_pad)), 1.0, -1.0)
+    return ClientBucket(idx, val, y * live, n_k)
+
+
+@pytest.mark.parametrize("solver", ["fsvrg", "fedavg", "fedavg-kernel"])
+def test_client_pass_over_batches_equals_one_vmap(monkeypatch, solver):
+    """A bucket of 11 clients in batches of 4, 4 and a last 3 gives the
+    deltas of one vmap over all 11: each client runs its own pass, from
+    its own key, whatever batch it lands in."""
+    from repro.core import fedavg, fsvrg
+
+    d, kb = 40, 11
+    bucket = _bucket(kb, 12, 8, d)
+    key = jax.random.PRNGKey(5)
+    w0 = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (d,))
+    if solver == "fsvrg":
+        grad = 0.01 * jax.random.normal(jax.random.PRNGKey(2), (d,))
+        phi = jax.random.uniform(jax.random.PRNGKey(3), (d,), minval=0.05)
+        run = lambda: fsvrg._client_pass(w0, grad, bucket, 0.01, phi,
+                                         fsvrg.FSVRGConfig(), key)
+    else:
+        cfg = fedavg.FedAvgConfig(stepsize=0.1, local_epochs=2)
+        run = lambda: fedavg._local_sgd_pass(
+            w0, bucket, 0.01, cfg, solver == "fedavg-kernel", key)
+
+    monkeypatch.setattr(problem_mod, "CLIENT_BLOCK_ELEMS", 1 << 30)
+    assert problem_mod.client_batch(d) >= kb
+    whole = np.asarray(run())
+    monkeypatch.setattr(problem_mod, "CLIENT_BLOCK_ELEMS", 4 * d)
+    assert problem_mod.client_batch(d) == 4
+    batched = np.asarray(run())
+    assert whole.shape == batched.shape == (kb, d)
+    assert np.abs(whole).max() > 0
+    np.testing.assert_array_max_ulp(batched, whole, maxulp=1)
+
+
 def test_client_batch_is_a_power_of_two_under_the_budget():
     for d in (1, 400, 2_002, 20_002, 3_000_000):
         b = problem_mod.client_batch(d)
         assert b >= 1 and b & (b - 1) == 0
         assert b * d <= problem_mod.CLIENT_BLOCK_ELEMS or b == 1
-    assert problem_mod.client_batch(20_002) == 32
+    assert problem_mod.client_batch(20_002) == 64
+
+
+#: the §4 buckets (clients, m_pad) of the chip benchmark's client sizes
+#: (``sizes_seed`` 0, 1,621,218 train rows over 10,000 clients)
+GPLUS_BUCKETS = [(331, 64), (6478, 128), (2121, 256), (715, 512),
+                 (239, 1020), (74, 2040), (31, 4078), (11, 6750)]
+
+
+def _steps(buckets, batch, chunk=None, epochs=1):
+    """Sequential local steps of a round's client pass: each bucket's
+    clients (padded to whole ``chunk``s where it has more) run as batches
+    of ``batch`` one after another, ``epochs`` passes of m_pad steps
+    each."""
+    ceil = lambda a, b: -(-a // b)
+    total = 0
+    for kb, m_pad in buckets:
+        if chunk is not None and kb > chunk:
+            batches = ceil(kb, chunk) * ceil(chunk, batch)
+        else:
+            batches = ceil(kb, batch)
+        total += batches * epochs * m_pad
+    return total
+
+
+def test_gplus_round_sequential_steps():
+    """The sequential steps of a round's client pass at the §4 width, FSVRG
+    (one pass) and FedAvg (512-client chunks, E = 2): each costs the chip
+    ~55 µs at up to ~32 clients and ~1.7–2.4 µs a client above that."""
+    batch = problem_mod.client_batch(20_002)
+    assert _steps(GPLUS_BUCKETS, batch) == 47_276
+    assert _steps(GPLUS_BUCKETS, batch, chunk=512, epochs=2) == 102_232
+    # the 32-client batch these replaced
+    assert _steps(GPLUS_BUCKETS, 32) == 80_724
+    assert _steps(GPLUS_BUCKETS, 32, chunk=512, epochs=2) == 178_600
 
 
 @pytest.mark.parametrize("nnz,width", [(1, 1), (8, 8), (14, 16), (62, 64),
