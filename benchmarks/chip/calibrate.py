@@ -16,7 +16,8 @@ and for the first ``--broken`` seeds, each against the float32 reference:
 * ``control``: the reference computed in bfloat16, the precision below
   the configuration's float32, put in the program's place;
 * ``half_cohort``: the reference with every other participating client
-  left out and the weighted mean taken over the rest.
+  left out and the weighted mean taken over the rest (the plain sum, for
+  a module that declares ``WEIGHTING = "sum"``).
 
 and, with no run, ``unchanged``: rounds that leave the iterate at w = 0,
 which read 1 on ``update_norm_gap``, ``change_norm_gap`` and

@@ -6,8 +6,15 @@ this directory:
 
     configs/<config>.json        the file BENCHMARK.json names for it
     traffic/<traffic>.json       solver arguments and eval cadence
-    metrics/<metric>.py          ``read(ctx) -> number or None``
-    reference/<solver>.py        the solver's plain reference pass
+    metrics/<metric>.py          ``read(ctx) -> number or None``; ``ctx``
+                                 holds the window, the set-up's laps, the
+                                 program's counters, and with ``--trace 1``
+                                 the trace and the program's spans and
+                                 scopes in it (``run.run_cell``)
+    reference/<solver>.py        the solver's plain reference pass; it may
+                                 carry per-client state (``init_state``)
+                                 and declare ``WEIGHTING = "sum"``
+                                 (``reference/common.py``)
     work/<solver>.py             the round's required work
     work/<solver>.<kernel>.py    a kernel's required work in that round
     limits/<workload>.json       the limit of each number compared

@@ -18,7 +18,9 @@ from a profiler trace of the window and the round's compiled HLO text:
   the nested ``XLA Ops`` line, clipped to the window;
 * :meth:`Program.span_modules_s` is the device time of the module
   executions dispatched under a host span (the innermost ``fl.*`` span
-  around the dispatch).
+  around the dispatch);
+* :meth:`Program.readings` turns them into the per-layer metrics a round
+  that the readers under ``metrics/`` return.
 
 A module execution is joined to its dispatch through the trace's flows.
 Its ``XLA Modules`` event consumes a flow (``_c``) that the runtime's
@@ -31,13 +33,11 @@ it: ``DoEnqueueProgram`` → ``tpu::System::Execute=>IssueSequencedEvent``
 spans, the Python thread.  That event's start is the dispatch; the
 enqueue itself may run later, on a runtime thread.
 
-Run as a script, it runs one traced window of a cell as ``run.py`` does
-(``run.prepare``, the benchmark's spans, the same profiler options and
-rounds), without the reference check, compiles the round from the cache
-for its HLO text, and prints one JSON line of readings per round next to
-``round_program_ms`` and ``eager_ms`` as ``trace_reduce`` reads them.
-``--tiny`` cuts the problem to a test size; ``--out`` keeps the trace and
-the HLO text, gzipped.
+Run as a script, it makes one ``run.py --trace 1`` run of a cell
+(``run.run_cell``) and prints its readings a round (``Program.readings``)
+and the idle gaps of its breakdown, as one JSON line.  ``--tiny`` cuts the
+problem to a test size; ``--out`` keeps the trace and the round's HLO
+text, gzipped: the test fixtures under ``tests/bench/data``.
 """
 from __future__ import annotations
 
@@ -50,7 +50,6 @@ import pathlib
 import re
 import shutil
 import sys
-import time
 
 HERE = pathlib.Path(__file__).resolve().parent
 if str(HERE) not in sys.path:
@@ -109,7 +108,8 @@ def inherited(hlo_text: str) -> dict:
     operands' (the first that has one), else that of the instruction that
     calls its computation.  The compiler's own instructions carry no
     ``op_name`` (a scatter rewritten to a fusion, a copy): this names the
-    work they came from.  Read only to describe unscoped time."""
+    work they came from, so that the scopes' metrics
+    (:meth:`Program.readings`) partition the round's module."""
     scope, comp_of, operands, calls = {}, {}, {}, collections.defaultdict(list)
     for name, comp, line in _instructions(hlo_text):
         scope[name], comp_of[name] = _scope(line), comp
@@ -205,6 +205,7 @@ class Program:
         self.round_module = round_module
         self.unjoined = unjoined      # module executions in the window
         self._ops = None
+        self._readings = {}
 
     def _devices(self):
         return len(self.reduced.devices) or 1
@@ -254,10 +255,11 @@ class Program:
                       reverse=True)[:top]
         return [[n, v, what[n], self.inherited.get(n)] for v, n in rows]
 
-    def scope_s(self, scope: str) -> float:
+    def scope_s(self, scope: str, inherit: bool = False) -> float:
         """Device seconds in which the innermost running operation of the
-        round's module carries ``scope``."""
-        return self.scope_table().get(scope, 0.0)
+        round's module carries ``scope`` (or, with ``inherit``, carries or
+        inherits it: :func:`inherited`)."""
+        return self.scope_table(inherit).get(scope, 0.0)
 
     def span_table(self) -> dict:
         """{span: device seconds} of the module executions in the window,
@@ -275,25 +277,18 @@ class Program:
         """Device seconds of module executions dispatched under ``span``."""
         return self.span_table().get(span, 0.0)
 
-    def label(self, t) -> str:
-        """The innermost ``fl.*`` span at ``t``, else the benchmark's."""
-        return _innermost(self.spans, t) or self.reduced._label(t)
-
-    def idle_gaps(self, top: int = trace_reduce.TOP):
-        """The longest idle stretches of the device in the window, each
-        named by :meth:`label` at its middle."""
-        t = self.reduced
-        gaps = []
-        for d in t.devices:
-            ops = [(s, e) for s, e, _ in t._clip(d["ops"])]
-            gaps.extend((e - s, (s + e) / 2)
-                        for s, e in trace_reduce._gaps(ops, t.lo, t.hi))
-        gaps.sort(reverse=True)
-        return [[self.label(mid), g * 1e-9] for g, mid in gaps[:top]]
-
     def readings(self, rounds: int) -> dict:
         """Per round, in ms: the metrics the program's spans and scopes
-        give, and the sums that check them against trace_reduce's."""
+        give (the readers under ``metrics/`` return them), and the sums
+        that check them against trace_reduce's.  A scope's metric counts
+        the unscoped instructions that inherit it (:func:`inherited`), so
+        the scopes leave no part of the round's module out; ``scopes_ms``
+        keeps each scope's own."""
+        if rounds not in self._readings:
+            self._readings[rounds] = self._read(rounds)
+        return self._readings[rounds]
+
+    def _read(self, rounds: int) -> dict:
         ms = 1e3 / rounds
         t = self.reduced
         scoped = self.scope_table()
@@ -303,8 +298,10 @@ class Program:
         eager = t.other_modules_s(self.round_module)
         phases = ("fl.prelude", "fl.eval", "fl.check_finite")
         return {
-            "client_pass_ms": self.scope_s("fl.client_pass") * ms,
-            "aggregate_ms": self.scope_s("fl.aggregate") * ms,
+            "client_pass_ms": by_flow.get("fl.client_pass", 0.0) * ms,
+            "aggregate_ms": by_flow.get("fl.aggregate", 0.0) * ms,
+            "gather_ms": (by_flow.get("fl.sample", 0.0)
+                          + by_flow.get("fl.gather", 0.0)) * ms,
             "full_grad_ms": self.span_modules_s("fl.prelude") * ms,
             "eval_ms": self.span_modules_s("fl.eval") * ms,
             "check_ms": self.span_modules_s("fl.check_finite") * ms,
@@ -402,48 +399,6 @@ def read(trace_path, hlo_path, round_module: str) -> Program:
         raw(trace_path)), raw(hlo_path).decode(), round_module)
 
 
-def trace_window(cell, seed: int, work: pathlib.Path):
-    """One traced window of ``cell`` as run.py's: the trace's path, the
-    round's compiled HLO text and its module's name, the rounds, the
-    window's seconds, and the compile counters at the window's start, at
-    its end and after the HLO text's compile."""
-    import jax
-
-    import run
-    from repro.utils import obs
-
-    s = run.prepare(cell, seed, run.Clock(time.perf_counter()))
-    rounds = cell.config.get("traced_rounds", run.TRACED_ROUNDS)
-    first = run.CHECKED_ROUNDS
-    last_only = cell.traffic["eval"] == "last_round"
-    eval_every = (first + rounds) if last_only else 1
-
-    def tick(st, r):
-        with s.spans.span("callback"):
-            s.spans.close_round()
-            s.spans.open_round()
-
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 1
-    opts.enable_hlo_proto = False
-    jax.profiler.start_trace(str(work), profiler_options=opts)
-    at_start = obs.counters()
-    t0 = time.perf_counter()
-    s.spans.open_round()
-    s.solver.fit(first + rounds, seed=seed, state=s.res.state,
-                 eval_fn=s.eval_f, eval_every=eval_every, callback=tick)
-    s.spans.close_round()
-    window_s = time.perf_counter() - t0
-    jax.profiler.stop_trace()
-    at_end = obs.counters()
-    hlo = s.solver.lower_round(s.solver.init(), jax.random.fold_in(
-        jax.random.PRNGKey(seed), 0)).compile().as_text()
-    return {"trace": sorted(work.glob("**/*.xplane.pb"))[-1], "hlo": hlo,
-            "module": s.round_module, "rounds": rounds, "window_s": window_s,
-            "counters": (at_start, at_end, obs.counters())}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -467,36 +422,16 @@ def main(argv=None) -> int:
         return 3
     run.use_compile_cache(root)
     seed = args.seed % run.SEED_MOD
-    work = HERE / "out" / f"program.{cell.name}.{seed}"
+    out_dir = HERE / "out" / f"program.{cell.name}.{seed}"
     try:
-        w = trace_window(cell, seed, work)
-        trace, hlo, rounds = w["trace"], w["hlo"], w["rounds"]
-        c0, c1, c2 = w["counters"]
-        t0 = time.perf_counter()
-        prog = from_xspace(jax.profiler.ProfileData.from_serialized_xspace(
-            trace.read_bytes()), hlo, w["module"])
-        record = {"workload": cell.name, "seed": seed, "tiny": args.tiny,
-                  "rounds": rounds, "window_s": w["window_s"],
-                  "trace_window_s": prog.reduced.window_s,
-                  "trace_events": prog.reduced.events,
-                  "cut": prog.reduced.cut(),
-                  **prog.readings(rounds),
-                  "idle_gaps": prog.idle_gaps(),
-                  "compile_at_window_start": c0,
-                  "compiles_in_window": c1["compiles"] - c0["compiles"],
-                  "compiles_for_hlo": c2["compiles"] - c1["compiles"],
-                  "cache_hits_for_hlo": c2["cache_hits"] - c1["cache_hits"],
-                  "reduce_s": time.perf_counter() - t0}
-        if args.out:
-            args.out.mkdir(parents=True, exist_ok=True)
-            stem = cell.name + (".tiny" if args.tiny else "")
-            with gzip.open(args.out / f"{stem}.xplane.pb.gz", "wb") as f:
-                f.write(trace.read_bytes())
-            with gzip.open(args.out / f"{stem}.hlo.txt.gz", "wb") as f:
-                f.write(hlo.encode())
+        rec = run.run_cell(cell, seed, 0.0, True, out_dir=out_dir,
+                           keep=args.out)
     finally:
-        shutil.rmtree(work, ignore_errors=True)
-    print(json.dumps(record))
+        shutil.rmtree(out_dir, ignore_errors=True)
+    print(json.dumps({"workload": cell.name, "seed": seed,
+                      "tiny": args.tiny, "correct": rec["correct"],
+                      "rounds": rec["attempted"], **rec["program"],
+                      "idle_gaps": rec["breakdown"]["idle_gaps"]}))
     return 0
 
 

@@ -17,11 +17,22 @@ as the round defines them (arXiv:1610.02527 §4 setting):
 * the server update is ``w + D ⊙ (s · Σ_k (n_k/n) δ_k)`` over the clients
   that took part, with ``s`` the expected over the realized weight mass
   (1 under full participation) and ``D`` the solver's diagonal (ones
-  unless it scales).
+  unless it scales);
+* a module that declares ``WEIGHTING = "sum"`` has each participant's
+  delta added with weight 1 and no reweighting: ``w + D ⊙ Σ_k δ_k``, the
+  plain sum of dual methods, whose deltas carry their own normalization.
 
 A solver's reference module supplies the rest: ``prepare`` (per-problem
 constants), ``prelude`` (per-round server state), ``deltas`` (one block
 of clients' passes) and ``server_diag``.
+
+Per-client state: a module may define ``init_state(data, layout, static,
+params, dtype)``, one array per group with a leading row per member (a
+dual block α_k, say).  ``deltas`` then takes the block's rows of it as
+``state=`` and returns ``(deltas, new_state)``, and the state is carried
+from round to round.  A client that takes no part in a round, or that the
+``half_cohort`` fault leaves out, keeps its state unchanged; pad slots of
+a block are never written back.
 """
 from __future__ import annotations
 
@@ -189,18 +200,23 @@ def run_rounds(data: Data, solver, params: dict, participation: float,
 
     ``fault`` breaks the round on purpose, for the checks that a broken
     round reads as not correct: ``"half_cohort"`` leaves out every other
-    client that takes part and takes the weighted mean over the rest."""
+    client that takes part and takes the weighted mean over the rest (the
+    plain sum over the rest, under ``WEIGHTING = "sum"``)."""
     t0 = time.perf_counter()
     spent = {}
+    summed = getattr(solver, "WEIGHTING", "nk") == "sum"
     with jax.default_matmul_precision("highest"):
         flat = Flat(data, dtype)
         layout = groups(data.sizes)
         rows = [group_rows(data, g, dtype) for g in layout]
         static = solver.prepare(data, flat, layout, params, dtype)
         diag = solver.server_diag(static)
-        weights = (data.sizes / data.n).astype(np.float32)
+        weights = (np.ones(len(data.sizes), np.float32) if summed
+                   else (data.sizes / data.n).astype(np.float32))
+        states = (list(solver.init_state(data, layout, static, params, dtype))
+                  if hasattr(solver, "init_state") else None)
         w = jnp.zeros((data.num_features,), dtype)
-        jax.block_until_ready((static, rows))
+        jax.block_until_ready((static, rows, states))
         spent["prepare"] = time.perf_counter() - t0
         out = []
         for r in range(rounds):
@@ -225,14 +241,22 @@ def run_rounds(data: Data, solver, params: dict, participation: float,
                 scale = np.float32(1.0)
                 if fault == "half_cohort" and len(chosen) > 1:
                     kept = chosen[::2]
-                    scale = np.float32(wts[chosen].sum() / wts[kept].sum())
+                    # a summed round reweights nothing: the plain sum
+                    # over the kept half
+                    if not summed:
+                        scale = np.float32(wts[chosen].sum()
+                                           / wts[kept].sum())
                     chosen = kept
-                acc = jax.block_until_ready(acc + scale * _group_sum(
+                g_sum, g_state = _group_sum(
                     solver, g_rows, chosen, keys, wts, w, ctx, static,
-                    params, dtype))
+                    params, dtype, None if states is None else states[gi])
+                acc = jax.block_until_ready(acc + scale * g_sum)
+                if states is not None:
+                    states[gi] = g_state
                 spent[f"group{gi}"] = spent.get(f"group{gi}", 0.0) + (
                     time.perf_counter() - t)
-            s = expected / max(realized, 1e-9) if participation < 1.0 else 1.0
+            s = (expected / max(realized, 1e-9)
+                 if participation < 1.0 and not summed else 1.0)
             step = jnp.float32(s) * acc
             if diag is not None:
                 step = diag * step
@@ -246,10 +270,11 @@ def run_rounds(data: Data, solver, params: dict, participation: float,
 
 
 def _group_sum(solver, g_rows, chosen, keys, wts, w, ctx, static, params,
-               dtype):
-    """Σ over the ``chosen`` members of a group of (n_k/n) δ_k, in float32,
-    in blocks of one shape per group; pad slots are clients with n_k = 0
-    and weight 0, exact no-ops."""
+               dtype, state=None):
+    """Σ over the ``chosen`` members of a group of their weight times δ_k,
+    in float32, in blocks of one shape per group, and the group's state
+    with the chosen members' rows replaced (None without state); pad slots
+    are clients with n_k = 0 and weight 0, exact no-ops."""
     idx, val, y, nk = g_rows
     # one of a few block shapes per group: a cohort's block is the next
     # power of two above its size, so padding never doubles the work
@@ -261,9 +286,14 @@ def _group_sum(solver, g_rows, chosen, keys, wts, w, ctx, static, params,
         real = np.arange(size) < len(block)
         take = jnp.asarray(np.where(real, np.resize(block, size), 0))
         real = jnp.asarray(real)
-        deltas = solver.deltas(w, idx[take], val[take], y[take],
-                               jnp.where(real, nk[take], 0), keys[take], ctx,
-                               static, params, dtype)
+        args = (w, idx[take], val[take], y[take],
+                jnp.where(real, nk[take], 0), keys[take], ctx, static,
+                params, dtype)
+        if state is None:
+            deltas = solver.deltas(*args)
+        else:
+            deltas, new = solver.deltas(*args, state=state[take])
+            state = state.at[jnp.asarray(block)].set(new[:len(block)])
         wb = jnp.where(real, jnp.asarray(wts)[take], 0.0)
         acc = acc + (wb[:, None] * deltas.astype(jnp.float32)).sum(0)
-    return acc
+    return acc, state

@@ -19,16 +19,22 @@ cadence), both named in ``BENCHMARK.json``.  A run:
    fill ``--seconds`` at the last warm round's pace, back to back; with
    ``--trace 1`` the same window under the profiler, at least two rounds
    or the configuration's ``traced_rounds``;
-3. reads the device's peak memory, frees the program's state, and runs
-   the plain reference (``reference/``) over the checked rounds from the
-   same rows and keys; ``correct`` holds when every number compared is
-   within its limit (``limits/``).
+3. reads the device's peak memory; with ``--trace 1`` then compiles the
+   round from the cache for its HLO text and reads the trace with it
+   (``program_trace``: the program's ``fl.*`` spans and scopes); frees the
+   program's state, and runs the plain reference (``reference/``) over the
+   checked rounds from the same rows and keys; ``correct`` holds when
+   every number compared is within its limit (``limits/``).
+
+Every run reads the program's compile counters (``repro.utils.obs``) at
+the window's start and end.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted`` and ``failed`` (rounds), ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``, each
 read by ``metrics/<name>.py``), ``device``, with ``--trace 1``
-``breakdown``, and last ``checks``: each number compared with its limit.
+``breakdown`` and ``program`` (the spans' and scopes' readings a round),
+and last ``checks``: each number compared with its limit.
 The run exits nonzero without that line when JAX finds no TPU or fewer
 chips than the cell asks for, or when the checkout lacks the program.
 """
@@ -219,13 +225,17 @@ def prepare(cell, seed: int, clock: Clock, break_round=None):
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, *,
-             out_dir: pathlib.Path, break_round=None) -> dict:
+             out_dir: pathlib.Path, break_round=None, keep=None) -> dict:
     """Everything after the device check: returns the result record.
 
     ``break_round(solver)``, for the tests only, breaks the timed path
-    underneath the harness after the solver is made."""
+    underneath the harness after the solver is made.  ``keep``, a
+    directory, keeps a traced run's trace and the round's HLO text there,
+    gzipped."""
     import jax
     import numpy as np
+
+    from repro.utils import obs
 
     clock = Clock(T_START)
     s = prepare(cell, seed, clock, break_round)
@@ -249,6 +259,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         opts.host_tracer_level = 1
         opts.enable_hlo_proto = False
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    at_start = obs.counters()
     setup_s = time.perf_counter() - T_START
     t0 = time.perf_counter()
     spans.open_round()
@@ -259,6 +270,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     window_s = time.perf_counter() - t0
     if trace:
         jax.profiler.stop_trace()
+    at_end = obs.counters()
     finite = bool(np.isfinite(np.asarray(res.w)).all())
 
     dev = jax.devices()[0]
@@ -271,11 +283,15 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         "cell": cell, "setup": {"setup_s": setup_s, **clock.parts},
         "window": {"seconds": window_s, "rounds": rounds},
         "memory": memory, "round_module": s.round_module, "trace": None,
+        "program": None, "counters": {"start": at_start, "end": at_end},
     }
     if trace:
         import peaks
-        import trace_reduce
-        ctx["trace"] = trace_reduce.reduce(trace_dir)
+        ctx["program"] = read_program(trace_dir, s.solver, seed,
+                                      s.round_module,
+                                      None if keep is None
+                                      else pathlib.Path(keep) / cell.name)
+        ctx["trace"] = ctx["program"].reduced
         shutil.rmtree(out_dir, ignore_errors=True)
         cut = ctx["trace"].cut()
         if cut:
@@ -305,14 +321,46 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     record = {"correct": ok, "attempted": rounds,
               "failed": 0 if finite else rounds, "metrics": metrics,
-              "device": device, "setup": ctx["setup"], "check_s": check_s}
+              "device": device, "setup": ctx["setup"], "check_s": check_s,
+              # 0 where every program came from set-up or the cache
+              "compiles_in_window": at_end["compiles"] - at_start["compiles"]}
     if trace:
         t = ctx["trace"]
         record["device"].update(busy_s=t.busy_s, window_s=t.window_s)
         record["trace_events"] = t.events
         record["breakdown"] = t.breakdown()
+        record["program"] = ctx["program"].readings(rounds)
     record["checks"] = checks
     return record
+
+
+def read_program(trace_dir, solver, seed: int, round_module: str,
+                 keep=None):
+    """The window's trace with the program's spans and scopes
+    (``program_trace.Program``): the round is compiled again for its HLO
+    text, which loads it from the cache, after the window and the memory
+    reading.  ``keep``, a path stem, keeps the trace and the HLO text as
+    ``<stem>.xplane.pb.gz`` and ``<stem>.hlo.txt.gz``."""
+    import gzip
+
+    import jax
+
+    import program_trace
+    hlo = solver.lower_round(solver.init(), jax.random.fold_in(
+        jax.random.PRNGKey(seed), 0)).compile().as_text()
+    files = sorted(trace_dir.glob("**/*.xplane.pb"))
+    if not files:
+        raise RuntimeError(f"the profiler wrote no trace under {trace_dir}")
+    raw = files[-1].read_bytes()
+    if keep is not None:
+        keep.parent.mkdir(parents=True, exist_ok=True)
+        for suffix, data in ((".xplane.pb.gz", raw),
+                             (".hlo.txt.gz", hlo.encode())):
+            with gzip.open(keep.with_name(keep.name + suffix), "wb") as f:
+                f.write(data)
+    return program_trace.from_xspace(
+        jax.profiler.ProfileData.from_serialized_xspace(raw), hlo,
+        round_module)
 
 
 def traced_rounds(cell, spec, seed, first, rounds):

@@ -7,8 +7,8 @@ the ``XLA Ops`` line one event per operation, named by its HLO text
 (``%<name> = <shape> <opcode>(...)``), a loop's body ops nested inside the
 loop's event.  A Pallas kernel is a ``custom-call`` named after the jitted
 function that calls ``pallas_call`` (``%fused_aggregate.7``).  Host planes
-hold the benchmark's own spans (``round``, ``eval_f``, ``callback``), on
-the same clock.
+hold the benchmark's own spans (``round``, ``eval_f``, ``callback``) and
+the program's (``fl.*``, ``repro.utils.obs``), on the same clock.
 
 The window runs from the start of the first ``round`` span to the end of
 the last.  Busy time is the union of the operation intervals inside it,
@@ -23,14 +23,16 @@ run reads no metric from such a trace.
 from __future__ import annotations
 
 import collections
-import glob
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 SPANS = ("round", "eval_f", "callback")
-#: which span names an idle gap, innermost first
+#: the program's own spans start with this
+PROGRAM_PREFIX = "fl."
+#: which of the benchmark's spans names an idle gap, innermost first; the
+#: innermost program span around the gap comes before them
 SPAN_ORDER = ("eval_f", "callback", "round")
 TOP = 10
 #: a module execution this long (ns) has to be covered by its operations
@@ -89,7 +91,7 @@ class Reduced:
         #                   "modules": [(start, end, name)]}; op names are
         # the HLO instruction names, kernels the custom calls among them
         self.devices = devices
-        self.spans = spans          # [(start, end, name)]
+        self.spans = spans          # [(start, end, name)], both kinds
         self.events = events        # device events the trace holds
         rounds = [(s, e) for s, e, n in spans if n == "round"]
         if rounds and devices:
@@ -193,18 +195,17 @@ class Reduced:
                               for g, mid in gaps[:TOP]]}
 
     def _label(self, t) -> str:
+        """The innermost program span (``fl.*``) around ``t``, else the
+        first of the benchmark's spans there in ``SPAN_ORDER``."""
+        program = [(s, n) for s, e, n in self.spans
+                   if s <= t <= e and n.startswith(PROGRAM_PREFIX)]
+        if program:
+            return max(program)[1]
         live = {n for s, e, n in self.spans if s <= t <= e}
         for name in SPAN_ORDER:
             if name in live:
                 return name
         return "outside spans"
-
-
-def read(path: str) -> Reduced:
-    import jax
-    with open(path, "rb") as f:
-        return from_xspace(jax.profiler.ProfileData.from_serialized_xspace(
-            f.read()))
 
 
 def from_xspace(data) -> Reduced:
@@ -230,13 +231,6 @@ def from_xspace(data) -> Reduced:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 spans.extend((e.start_ns, e.end_ns, e.name)
-                             for e in line.events if e.name in SPANS)
+                             for e in line.events if e.name in SPANS
+                             or e.name.startswith(PROGRAM_PREFIX))
     return Reduced(devices, spans, events)
-
-
-def reduce(trace_dir) -> Reduced:
-    """The newest trace under ``trace_dir``."""
-    files = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not files:
-        return Reduced([], [])
-    return read(files[-1])

@@ -7,9 +7,11 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 CHIP = ROOT / "benchmarks" / "chip"
 CELLS = tuple(w["name"] for w in json.loads(
     (ROOT / "BENCHMARK.json").read_text())["workloads"])
-#: the cells, and the cohort mix on both configurations, which the tests
-#: run at their size (``tiny_root``)
-MIXES = CELLS + ("fedavg-gplus.cohort10", "fsvrg-gplus.cohort10")
+#: the cohort mix on both configurations, which the tests run at their
+#: size (``tiny_root``) whether or not BENCHMARK.json has the cell
+COHORTS = ("fedavg-gplus.cohort10", "fsvrg-gplus.cohort10")
+#: the cells, and the cohort mixes that are not cells
+MIXES = CELLS + tuple(c for c in COHORTS if c not in CELLS)
 
 #: 60 clients, 200 features, 12 nonzeros a row
 TINY_PROBLEM = {"num_clients": 60, "num_features": 200, "num_examples": 6000,

@@ -41,6 +41,8 @@ def test_names_units_and_lines_use_only_the_allowed_characters():
     for m in metrics:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
     for m in BENCH["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
@@ -60,6 +62,26 @@ def test_every_name_leads_to_its_files(name):
         assert callable(cell.reader(m["name"]).read)
     assert set(cell.limits) == {"loss_gap", "update_norm_gap",
                                 "change_norm_gap", "iterate_gap"}
+
+
+def test_every_cell_has_limits_and_every_reference_its_hooks():
+    """Each cell's limits are a file of their own; each solver's reference
+    module has the four functions ``reference/common.py`` calls, and a
+    weighting it knows."""
+    for w in BENCH["workloads"]:
+        assert (CHIP / "limits" / f"{w['name']}.json").is_file(), w["name"]
+    stems = set()
+    for path in sorted((CHIP / "reference").glob("*.py")):
+        if path.stem == "common":
+            continue
+        stems.add(path.stem)
+        mod = catalog.load_module(path, "reference")
+        for fn in ("prepare", "prelude", "deltas", "server_diag"):
+            assert callable(getattr(mod, fn)), (path.stem, fn)
+        assert getattr(mod, "WEIGHTING", "nk") in ("nk", "sum")
+    assert {"fsvrg", "fedavg", "cocoa"} <= stems
+    assert callable(catalog.load_module(
+        CHIP / "reference" / "cocoa.py", "reference").init_state)
 
 
 def test_a_new_mix_metric_and_cell_are_found_by_adding_files(tmp_path):

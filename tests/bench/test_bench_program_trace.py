@@ -59,22 +59,6 @@ def test_self_time_goes_to_the_innermost_running_operation():
             "loop": 60, "body": 50}
 
 
-def test_an_idle_gap_takes_the_innermost_program_span():
-    ops = [(10, 20, "%a")]
-    modules = [(10, 20, "jit__body(1)", "fl.dispatch")]
-    reduced = trace_reduce.Reduced(
-        [{"ops": ops, "kernels": [], "modules": [m[:3] for m in modules]}],
-        [(0, 100, "round"), (60, 80, "eval_f")])
-    spans = [(0, 100, "fl.round"), (50, 90, "fl.eval")]
-    prog = program_trace.Program(reduced, spans, [modules], "", "jit__body")
-    # 20..100 (mid 60, in fl.eval inside eval_f), 0..10 (fl.round)
-    assert prog.idle_gaps() == [["fl.eval", pytest.approx(80e-9)],
-                                ["fl.round", pytest.approx(10e-9)]]
-    assert prog.label(95) == "fl.round"
-    prog.spans = []
-    assert prog.label(70) == "eval_f"
-
-
 @pytest.fixture(scope="module")
 def fedavg_trace():
     """The one-round FedAvg chip trace of test_bench_trace.py, recorded
@@ -95,7 +79,8 @@ def test_every_module_of_the_fedavg_trace_is_joined_to_a_dispatch(
             t.module_s("jit__body") + t.other_modules_s("jit__body"),
             rel=1e-12)}
     # without program spans the gaps keep the benchmark's labels
-    assert fedavg_trace.idle_gaps() == t.breakdown()["idle_gaps"]
+    gaps = t.breakdown()["idle_gaps"]
+    assert gaps and all(label in trace_reduce.SPANS for label, _ in gaps)
 
 
 @pytest.fixture(scope="module")
@@ -152,15 +137,13 @@ def test_chip_trace_names_the_unscoped_operations(fsvrg_trace):
 
 
 def test_chip_trace_names_idle_gaps_by_program_spans(fsvrg_trace):
-    # one gap falls after the last round's spans close, in the benchmark's
-    # tail round span
-    assert [label for label, _ in fsvrg_trace.idle_gaps()] == [
+    # the breakdown the ledger keeps; one gap falls after the last round's
+    # spans close, in the benchmark's tail round span
+    assert [label for label, _ in fsvrg_trace.reduced.breakdown()[
+        "idle_gaps"]] == [
         "fl.eval", "fl.eval", "fl.check_finite", "fl.check_finite",
         "fl.round", "round", "fl.round", "fl.prelude", "fl.prelude",
         "fl.prelude"]
-    # the benchmark's own reading of the same trace keeps its labels
-    assert all(label in trace_reduce.SPANS for label, _ in
-               fsvrg_trace.reduced.breakdown()["idle_gaps"])
 
 
 def test_the_hlo_text_is_the_round_module():

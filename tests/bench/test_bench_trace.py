@@ -93,8 +93,8 @@ def test_a_trace_is_cut_where_the_device_goes_quiet_before_the_end():
     assert "before the end of the last round" in cut
 
 
-def test_an_empty_trace_reads_nothing(tmp_path):
-    t = trace_reduce.reduce(tmp_path)
+def test_an_empty_trace_reads_nothing():
+    t = trace_reduce.Reduced([], [])
     assert t.busy_s == 0.0 and t.window_s == 0.0
     assert t.breakdown() == {"device_ops": [], "idle_gaps": []}
 
