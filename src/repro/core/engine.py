@@ -725,7 +725,7 @@ class RoundEngine:
                                     masks[bi] if masks is not None else None)
             if masks is not None:
                 sel = masks[bi]
-                with obs.scope("fl.client_pass"):
+                with obs.scope("fl.state"):
                     s_b = jax.tree_util.tree_map(
                         lambda new, old: jnp.where(
                             sel.reshape((b.num_clients,)
@@ -810,7 +810,8 @@ class RoundEngine:
                 "wts": chunked(wts),
             }
         if state_b is not None:
-            xs["state"] = jax.tree_util.tree_map(chunked, state_b)
+            with obs.scope("fl.state"):
+                xs["state"] = jax.tree_util.tree_map(chunked, state_b)
         if sel is not None:
             xs["sel"] = chunked(sel)
         if self.fault_model is not None:
@@ -836,12 +837,13 @@ class RoundEngine:
                     deltas, s_new = chunk_pass(w, bi, cb, x["state"],
                                                x["keys"])
                     if sel is not None:
-                        s_new = jax.tree_util.tree_map(
-                            lambda new, old: jnp.where(
-                                x["sel"].reshape((chunk,)
-                                                 + (1,) * (new.ndim - 1))
-                                > 0, new, old),
-                            s_new, x["state"])
+                        with obs.scope("fl.state"):
+                            s_new = jax.tree_util.tree_map(
+                                lambda new, old: jnp.where(
+                                    x["sel"].reshape((chunk,)
+                                                     + (1,) * (new.ndim - 1))
+                                    > 0, new, old),
+                                s_new, x["state"])
             if self.fault_model is not None:
                 # live = the chunk's (already sel-zeroed) weights: only
                 # clients actually contributing to the sum can be faulted
@@ -858,8 +860,10 @@ class RoundEngine:
         acc, s_stack = jax.lax.scan(body, jnp.zeros_like(w), xs)
         if state_b is None:
             return acc, None
-        new_state = jax.tree_util.tree_map(
-            lambda a: a.reshape((nch * chunk,) + a.shape[2:])[:Kb], s_stack)
+        with obs.scope("fl.state"):
+            new_state = jax.tree_util.tree_map(
+                lambda a: a.reshape((nch * chunk,) + a.shape[2:])[:Kb],
+                s_stack)
         return acc, new_state
 
     def _streamed_round(self, w, key, chunk_pass, states, masks, *,
@@ -1013,11 +1017,13 @@ class RoundEngine:
         else:
             deltas, s_new = chunk_pass(w, bi, bucket, state_b, keys)
             if sel is not None:
-                s_new = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(
-                        sel.reshape((bucket.num_clients,)
-                                    + (1,) * (new.ndim - 1)) > 0, new, old),
-                    s_new, state_b)
+                with obs.scope("fl.state"):
+                    s_new = jax.tree_util.tree_map(
+                        lambda new, old: jnp.where(
+                            sel.reshape((bucket.num_clients,)
+                                        + (1,) * (new.ndim - 1)) > 0,
+                            new, old),
+                        s_new, state_b)
         if self.fault_model is not None:
             deltas = self._faulted(deltas, r, ids, wtsz)
         deltas = self._guard_clip(deltas)
@@ -1209,11 +1215,13 @@ class RoundEngine:
                         deltas, s_new = chunk_pass(w, bi, bucket, state_b,
                                                    keys)
                         if sel is not None:
-                            s_new = jax.tree_util.tree_map(
-                                lambda new, old: jnp.where(
-                                    sel.reshape((Kb,) + (1,) * (new.ndim - 1))
-                                    > 0, new, old),
-                                s_new, state_b)
+                            with obs.scope("fl.state"):
+                                s_new = jax.tree_util.tree_map(
+                                    lambda new, old: jnp.where(
+                                        sel.reshape((Kb,)
+                                                    + (1,) * (new.ndim - 1))
+                                        > 0, new, old),
+                                    s_new, state_b)
                 if self.fault_model is not None:
                     deltas = self._faulted(deltas, r, ids, sel)
                 stacks.append(deltas)

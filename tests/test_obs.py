@@ -33,6 +33,16 @@ PATHS = {
               {"fl.client_pass", "fl.aggregate"}),
     "with_state": ("cocoa", {}, "tiny_problem",
                    {"fl.client_pass", "fl.aggregate"}),
+    # per-client state moves (frozen, chunked, written back) under fl.state
+    "with_state_partial": ("cocoa", {"participation": 0.5}, "tiny_problem",
+                           {"fl.sample", "fl.client_pass", "fl.state",
+                            "fl.aggregate"}),
+    "with_state_streamed": ("cocoa", {"client_chunk": 2}, "tiny_problem",
+                            {"fl.client_pass", "fl.state", "fl.aggregate"}),
+    "with_state_cohort": ("cocoa", {"participation": 0.5, "cohort": 2,
+                                    "client_chunk": 2}, "tiny_problem",
+                          {"fl.sample", "fl.gather", "fl.client_pass",
+                           "fl.state", "fl.aggregate"}),
     "streamed": ("fedavg", {"client_chunk": 2}, "tiny_problem",
                  {"fl.client_pass", "fl.aggregate"}),
     "cohort": ("fedavg", {"participation": 0.5, "cohort": 2},

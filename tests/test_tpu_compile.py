@@ -15,6 +15,7 @@ an entry compiled for a described chip cannot be read back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +104,37 @@ def test_cocoa_sdca_update_compiles(one_chip, kb):
     v = _vec(one_chip, kb)
     c = _compile(cocoa_sdca.cocoa_sdca_update, v, v, v)
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_sdca_pass_names_its_kernel_after_the_wrapper(one_chip, monkeypatch):
+    """One 64-client batch's SDCA pass as CoCoA+ runs it on a TPU (a
+    lockstep scan over the row slots, the kernel once a step): the
+    kernel's custom call is named after its jitted wrapper,
+    ``cocoa_sdca_update``, the name the chip benchmark's
+    ``kernel_ms.cocoa_sdca`` looks for in the device trace."""
+    from repro.core.cocoa import _sdca_local_pass_keyed
+    from repro.core.problem import ClientBucket
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    clients, slots, width = 64, 128, 62
+
+    def sdca_pass(w, alpha, idx, val, y, n_k, keys):
+        return _sdca_local_pass_keyed(w, alpha,
+                                      ClientBucket(idx, val, y, n_k),
+                                      1e-6, 1e6, float(K), True, keys)
+
+    c = _compile(sdca_pass, _vec(one_chip),
+                 _spec(one_chip, (clients, slots)),
+                 _spec(one_chip, (clients, slots, width), jnp.int32),
+                 _spec(one_chip, (clients, slots, width)),
+                 _spec(one_chip, (clients, slots)),
+                 _spec(one_chip, (clients,), jnp.int32),
+                 _spec(one_chip, (clients, 2), jnp.uint32))
+    calls = [line.split("=", 1)[0].strip() for line in
+             c.as_text().splitlines() if "tpu_custom_call" in line]
+    assert calls and all(re.fullmatch(r"%cocoa_sdca_update(\.\d+)?", n)
+                         for n in calls), calls
 
 
 def test_fused_aggregate_compiles(one_chip):
